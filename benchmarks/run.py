@@ -38,9 +38,15 @@ from __future__ import annotations
 import argparse
 import time
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 import jax.numpy as jnp
@@ -828,7 +834,7 @@ def serve_restart(full: bool):
             f"rps={R / t_pop:.2f} builds={st_pop['builds']} "
             f"saved={st_pop['store']['saved']}")
         t_warm, st_warm = boot_and_serve(DurableProgramStore(d))
-        assert st_warm["builds"] == 0 or not st_warm["store"]["serializable"]
+        assert st_warm["builds"] == 0
         row(f"serve_restart/warm_store_boot_R{R}", t_warm * 1e6,
             f"rps={R / t_warm:.2f} builds={st_warm['builds']} "
             f"loaded={st_warm['store']['loaded']} "

@@ -14,9 +14,15 @@ Everything goes through ``repro.api.slope_path``: a ``Problem`` +
 the planner (``res.plan.explain()`` says what ran and why).
 """
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import time
 

@@ -4,9 +4,15 @@ multinomial — each fitted with and without the strong rule.
     PYTHONPATH=src python examples/glm_families.py
 """
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 

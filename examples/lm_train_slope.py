@@ -15,6 +15,10 @@ import signal
 
 import numpy as np
 
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 from repro.configs import get_config
 from repro.models.slope_reg import SlopeRegConfig
 from repro.optim import AdamWHyper

@@ -7,9 +7,15 @@ screening rule — and shows (a) identical estimates, (b) the screened-set
 sizes, (c) the wall-clock speedup.  This is the paper's headline result.
 """
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import time
 

@@ -8,6 +8,7 @@ the same ``decode_step`` the multi-pod dry-run lowers at
 (arch × decode_32k × 512 devices).
 """
 
+import os
 import sys
 import time
 
@@ -15,6 +16,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from repro.configs import get_config
 from repro.models import decode_step, init_cache, init_params

@@ -21,9 +21,15 @@ metrics registry behind ``svc.stats()`` is dumped in Prometheus text
 format at the end.
 """
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import time
 

@@ -9,9 +9,15 @@ frequencies next to the single-path support, and closes with
 permutation p-values for the same predictors.
 """
 
+import os
+
 import jax
 
 jax.config.update("jax_enable_x64", True)
+
+from repro.compile_cache import use_checkout_cache
+
+use_checkout_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 

@@ -138,7 +138,7 @@ def slope_path(problem: Problem, path: PathSpec | None = None,
         X, y = apply_weights(problem)
     family = problem.family
     n, p, m = problem.n, problem.p, family.n_classes
-    lam = path.lam.resolve(p * m, n=n)
+    lam = path.lam.resolve(p * m, n=n, dtype=X.dtype)
     if policy.validate == "strict":
         issues = find_nonfinite(X=X, y=y, lam=lam, sigmas=path.sigmas)
         if issues:
@@ -229,7 +229,7 @@ def _resample_path(problem: Problem, path: PathSpec, policy: SolverPolicy,
     y = np.asarray(problem.y)
     family = problem.family
     n, p, m = problem.n, problem.p, family.n_classes
-    lam = path.lam.resolve(p * m, n=n)
+    lam = path.lam.resolve(p * m, n=n, dtype=X.dtype)
     if getattr(lam, "ndim", 1) != 1:
         raise ValueError(
             "replicates share ONE design, so they share one (p·m,) λ "

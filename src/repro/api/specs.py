@@ -237,8 +237,10 @@ class LambdaSpec:
         return cls(kind="explicit", values=values)
 
     def resolve(self, size: int, *, n: int | None = None,
-                canonicalizer: LambdaCanonicalizer | None = None) -> np.ndarray:
-        """The concrete ``(size,)`` sequence (size = p·m coefficients)."""
+                canonicalizer: LambdaCanonicalizer | None = None,
+                dtype=None) -> np.ndarray:
+        """The concrete ``(size,)`` sequence (size = p·m coefficients); a
+        named one is built in the float dtype of ``dtype`` (the design's)."""
         if self.kind == "explicit":
             lam = np.asarray(self.values)
             # (size,) shared sequence, or a per-problem (B, size) stack for
@@ -249,7 +251,7 @@ class LambdaSpec:
                     f"got shape {lam.shape}")
             return lam
         canon = canonicalizer if canonicalizer is not None else _SHARED_CANONICALIZER
-        return canon.get(self.kind, self.q, size, n=n)
+        return canon.get(self.kind, self.q, size, n=n, dtype=dtype)
 
 
 def as_lambda_spec(lam) -> LambdaSpec:

@@ -30,7 +30,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .screening import screen_k
 
@@ -49,11 +48,11 @@ def sharded_linear_predictor(mesh: Mesh, axis: str):
     """z = Xβ with X and β column/feature-sharded: local matvec + psum."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def z_fn(X_local, beta_local):
         return jax.lax.psum(X_local @ beta_local, axis)
@@ -65,11 +64,11 @@ def sharded_gradient(mesh: Mesh, axis: str):
     """∇f shard: Xᵀr needs no communication when X is column-sharded."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, axis), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     def g_fn(X_local, r):
         return X_local.T @ r
@@ -99,11 +98,11 @@ def distributed_strong_rule(mesh: Mesh, axis: str, *, cap: int, p_total: int):
     """
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(), P(), P(), P()),
         out_specs=(P(), P(), P(axis), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def screen_fn(grad_local, gap_cap, lam_cap, lam_min, gap_tail_max):
         mag_local = jnp.abs(grad_local)

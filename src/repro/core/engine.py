@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..serve.buckets import BucketRegistry, next_pow2
+from ..serve.buckets import STEP_CHUNK, BucketRegistry, next_pow2
 from .kkt import kkt_violations_masked
 from .lambda_seq import path_start_sigma, sigma_grid
 from .losses import Family
@@ -1139,7 +1139,8 @@ def null_sigma_grid(X, y, lam, family: Family, *, path_length: int,
         grad0 = null_gradient(X, y, family)
     s1 = float(path_start_sigma(jnp.asarray(grad0), jnp.asarray(lam)))
     n, p = X.shape
-    return sigma_grid(s1, length=path_length, ratio=sigma_ratio, n=n, p=p)
+    return sigma_grid(s1, length=path_length, ratio=sigma_ratio, n=n, p=p,
+                      dtype=X.dtype)
 
 
 def _null_sigma_grids(Xs, ys, lam, family: Family, path_length, sigma_ratio):
@@ -1163,6 +1164,47 @@ def _null_sigma_grids(Xs, ys, lam, family: Family, path_length, sigma_ratio):
 _WS_BUCKETS = BucketRegistry(name="working_set", capacity=256)
 
 _next_pow2 = next_pow2  # promoted to repro.serve.buckets; alias kept local
+
+
+def _chunked_path(Xs, ys, lam, sigmas, family: Family, p_valid, *,
+                  step_chunk: int, **engine_kw) -> EnginePath:
+    """The masked path run the way the async service runs it: the
+    :func:`path_init_engine` prefill, then :func:`chunk_path_engine` calls
+    of ``step_chunk`` σ-steps with the carry round-tripped through the
+    host.  Bitwise the service's result on any backend (same programs,
+    same operands); on the CPU also bitwise :func:`batched_path_engine`'s.
+    """
+    S, _, P = Xs.shape
+    m = family.n_classes
+    f = Xs.dtype
+    L = sigmas.shape[1]
+    C = step_chunk
+    g0, nd0, L0, h0 = (np.asarray(a) for a in
+                       path_init_engine(jnp.asarray(Xs), jnp.asarray(ys),
+                                        family))
+    carry = (np.zeros((S, P, m), f), g0, np.zeros((S, P), bool), L0, h0)
+    parts = []
+    for start in range(1, L, C):
+        take = min(C, L - start)
+        sig_prev = np.ones((S, C), f)
+        sig_next = np.ones((S, C), f)
+        live = np.zeros((S, C), bool)
+        sig_prev[:, :take] = sigmas[:, start - 1:start - 1 + take]
+        sig_next[:, :take] = sigmas[:, start:start + take]
+        live[:, :take] = True
+        out, ep = chunk_path_engine(Xs, ys, lam, sig_prev, sig_next, live,
+                                    *carry, family, p_valid, **engine_kw)
+        carry = tuple(np.asarray(a) for a in out)
+        parts.append([np.asarray(a)[:, :take] for a in ep])
+    head = EnginePath(
+        betas=np.zeros((S, 1, P, m), f), n_active=np.zeros((S, 1), np.int32),
+        n_screened=np.zeros((S, 1), np.int32),
+        n_violations=np.zeros((S, 1), np.int32),
+        refits=np.zeros((S, 1), np.int32),
+        solver_iters=np.zeros((S, 1), np.int32), deviance=nd0[:, None],
+        kkt_unrepaired=np.zeros((S, 1), bool), health=h0[:, None])
+    return EnginePath(*(np.concatenate([h] + [pt[i] for pt in parts], axis=1)
+                        for i, h in enumerate(head)))
 
 
 def _ws_bucket(working_set, n: int, p: int, key: tuple) -> int:
@@ -1325,11 +1367,13 @@ def _fit_path_batched(
     Xs_run, ys_run, lam_run, sig_run = Xs, ys, lam, sigmas
     n_run, p_run = n, p
     if pad == "bucket":
-        from ..serve.buckets import default_policy, pad_batch
+        from ..serve.buckets import SERVE_SLOTS, default_policy, pad_batch
 
         policy = default_policy()
         n_run, p_run = policy.shape_bucket(n, p, family.name)
-        slots = policy.batch_bucket(B)
+        # the default service's slot count: a masked bucket fit runs its
+        # very programs (see _chunked_path)
+        slots = policy.batch_bucket(max(B, SERVE_SLOTS))
         lam2 = lam if lam.ndim == 2 else np.broadcast_to(lam, (B, p * m))
         pb = pad_batch(
             [(Xs[b], ys[b], lam2[b], sigmas[b]) for b in range(B)],
@@ -1343,7 +1387,10 @@ def _fit_path_batched(
     t0 = time.perf_counter()
     W = W2 = None
     stats = None
-    if working_set is None:
+    if working_set is None and pad == "bucket":
+        res = _chunked_path(Xs_run, ys_run, lam_run, sig_run, family,
+                            p_valid, step_chunk=STEP_CHUNK, **engine_kw)
+    elif working_set is None:
         res = batched_path_engine(
             jnp.asarray(Xs_run), jnp.asarray(ys_run), jnp.asarray(lam_run),
             jnp.asarray(sig_run), family, p_valid, **engine_kw)

@@ -62,9 +62,12 @@ def in_subdifferential(g, beta, lam, *, rtol: float = 1e-6, atol: float = 1e-6) 
         lam_slot = lam[pos: pos + len(members)]
         gs = g[members]
         active = mag[members[0]] > 0
-        if active and np.any((np.sign(gs) != np.sign(beta[members])) & (gs != 0)):
-            # sign condition binds where β ≠ 0 AND g ≠ 0 (g_j = 0 is always
-            # admissible — e.g. λ ≡ 0 gives ∂J = {0} regardless of signs)
+        if active and np.any((np.sign(gs) != np.sign(beta[members]))
+                             & (np.abs(gs) > tol)):
+            # sign condition binds where β ≠ 0 AND |g| exceeds the
+            # tolerance (g_j = 0 is always admissible — e.g. λ ≡ 0 gives
+            # ∂J = {0} regardless of signs — and a g_j within tol of 0 is
+            # within tol of an admissible value)
             return False
         c = np.sort(np.abs(gs))[::-1]
         if np.any(np.cumsum(c - lam_slot) > tol):

@@ -31,7 +31,15 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-__all__ = ["Family", "ols", "logistic", "poisson", "multinomial", "get_family"]
+__all__ = ["Family", "ols", "logistic", "poisson", "multinomial", "get_family",
+           "matmul"]
+
+
+def matmul(a, b):
+    """``a @ b`` at full precision.  A TPU runs an f32 product as one bf16
+    pass by default, which leaves a path solver's gradient ~1e-3 off; on
+    the CPU the full product is what runs anyway."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def _row_broadcast(w, a):
@@ -64,14 +72,15 @@ class Family:
 
     def loss(self, X, y, beta, weights=None):
         if weights is None:
-            return self.value(X @ beta, y)
-        return self.weighted_value(X @ beta, y, weights)
+            return self.value(matmul(X, beta), y)
+        return self.weighted_value(matmul(X, beta), y, weights)
 
     def gradient(self, X, y, beta, weights=None):
         """∇f(β) = Xᵀ r(Xβ, y); shape = beta.shape."""
         if weights is None:
-            return X.T @ self.residual(X @ beta, y)
-        return X.T @ self.weighted_residual(X @ beta, y, weights)
+            return matmul(X.T, self.residual(matmul(X, beta), y))
+        return matmul(X.T, self.weighted_residual(matmul(X, beta), y,
+                                                      weights))
 
     def loss_and_gradient(self, X, y, beta, weights=None):
         """(f(β), ∇f(β)) sharing ONE linear predictor z = Xβ.
@@ -82,11 +91,11 @@ class Family:
         the Xᵀr matvec.  The Pallas analogue is
         :func:`repro.kernels.slope_loss_residual`.
         """
-        z = X @ beta
+        z = matmul(X, beta)
         if weights is None:
-            return self.value(z, y), X.T @ self.residual(z, y)
+            return self.value(z, y), matmul(X.T, self.residual(z, y))
         return (self.weighted_value(z, y, weights),
-                X.T @ self.weighted_residual(z, y, weights))
+                matmul(X.T, self.weighted_residual(z, y, weights)))
 
     def lipschitz(self, X) -> jax.Array:
         """Upper bound on the gradient Lipschitz constant: c·‖X‖₂²."""
@@ -100,13 +109,13 @@ def _spectral_norm(X, iters: int = 30):
     v = jnp.ones((X.shape[1],), X.dtype) / jnp.sqrt(X.shape[1])
 
     def body(_, v):
-        u = X @ v
+        u = matmul(X, v)
         u = u / (jnp.linalg.norm(u) + 1e-12)
-        w = X.T @ u
+        w = matmul(X.T, u)
         return w / (jnp.linalg.norm(w) + 1e-12)
 
     v = jax.lax.fori_loop(0, iters, body, v)
-    return jnp.linalg.norm(X @ v)
+    return jnp.linalg.norm(matmul(X, v))
 
 
 # -- OLS --------------------------------------------------------------------

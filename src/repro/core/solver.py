@@ -38,6 +38,15 @@ DEFAULT_MAX_REFITS = 32
 # batch to the masked O(n·p) fallback.  1 pins the single-tier PR-2
 # behaviour, 2 demands the second tier (still capped below p).
 DEFAULT_WS_TIERS = "auto"
+# rounding floors of FISTA's tests, in units of the dtype's machine
+# epsilon: the objective-plateau tolerance, and the line search's allowed
+# excess.  Both sit below 1e-12 in f64, so f64 solves are unchanged.
+_TOL_ULPS = 16
+_SLACK_ULPS = 1024
+
+
+def _vdot(a, b):
+    return jnp.vdot(a, b, precision=lax.Precision.HIGHEST)
 
 
 def default_L0(X: jax.Array, family: Family,
@@ -125,6 +134,18 @@ def fista(
     """
     dtype = X.dtype
     lam = lam.astype(dtype)
+    eps = float(jnp.finfo(dtype).eps)
+    # in f32 a relative objective change of a few ulps is noise, and so is
+    # a line-search excess of that size: without these floors the plateau
+    # test never fires and the sufficient-decrease test rejects correct
+    # steps (L then doubles until the solve stalls).  A floored plateau
+    # test no longer certifies small coefficients (the objective carries
+    # the constant ½‖y‖², so a change that moves β by 10% can sit under
+    # the floor), so the fixed-point test then bounds the error relative
+    # to max|β| instead of max(1, max|β|).
+    plateau_tol = max(tol, _TOL_ULPS * eps)
+    stat_floor = 1.0 if plateau_tol == tol else 0.0
+    slack = max(1e-12, _SLACK_ULPS * eps)
 
     def obj_fn(beta):
         return family.loss(X, y, beta, weights=weights) + sorted_l1_norm(beta, lam)
@@ -160,9 +181,9 @@ def fista(
             )
             x_new = x_new.reshape(z.shape)
             diff = x_new - z
-            q = fz + jnp.vdot(gz, diff) + 0.5 * L * jnp.vdot(diff, diff)
+            q = fz + _vdot(gz, diff) + 0.5 * L * _vdot(diff, diff)
             fx = family.loss(X, y, x_new, weights=weights)
-            ok = fx <= q + 1e-12 * jnp.abs(q)
+            ok = fx <= q + slack * jnp.abs(q)
             L_next = jnp.where(ok, L, L * 2.0)
             return L_next, x_new, fx, J_scaled * L, ok, tries + 1
 
@@ -177,7 +198,7 @@ def fista(
         if restart:
             # Gradient-scheme restart (O'Donoghue & Candès): kill momentum
             # when the update opposes the trajectory.
-            bad = jnp.vdot(z - x_new, x_new - state.x) > 0
+            bad = _vdot(z - x_new, x_new - state.x) > 0
             t_new = jnp.where(bad, 1.0, t_new)
             z_new = jnp.where(bad, x_new, z_new)
 
@@ -187,9 +208,11 @@ def fista(
         # the objective at O(step²)), so also require the prox-gradient
         # fixed-point residual ‖x⁺ − z‖∞ ≲ √tol — that bounds coefficient
         # error at the same scale the objective test bounds the value
-        plateau = jnp.abs(state.obj - obj_new) <= tol * jnp.maximum(1.0, jnp.abs(obj_new))
+        plateau = (jnp.abs(state.obj - obj_new)
+                   <= plateau_tol * jnp.maximum(1.0, jnp.abs(obj_new)))
         resid = jnp.max(jnp.abs(x_new - z))
-        stationary = resid <= jnp.sqrt(tol) * jnp.maximum(1.0, jnp.max(jnp.abs(x_new)))
+        stationary = resid <= (jnp.sqrt(tol)
+                               * jnp.maximum(stat_floor, jnp.max(jnp.abs(x_new))))
         done = plateau & stationary
         # mild decrease of L lets the step size recover after conservative phases
         return _State(x_new, z_new, t_new, L * 0.95, obj_new, state.it + 1, done)

@@ -37,7 +37,7 @@ def sorted_l1_norm(beta: jax.Array, lam: jax.Array) -> jax.Array:
     """J(β; λ) = Σ λ_j |β|_(j) with |β|_(1) ≥ |β|_(2) ≥ …"""
     beta = jnp.ravel(beta)
     mag = jnp.sort(jnp.abs(beta))[::-1]
-    return jnp.dot(mag, lam.astype(mag.dtype))
+    return jnp.dot(mag, lam.astype(mag.dtype), precision=lax.Precision.HIGHEST)
 
 
 def isotonic_decreasing(y: jax.Array) -> jax.Array:
@@ -81,6 +81,14 @@ def isotonic_decreasing(y: jax.Array) -> jax.Array:
     return means[idx]
 
 
+def _segmented_add(a, b):
+    """Associative combine of a prefix sum that restarts at flagged
+    positions: ``(flag, value)`` pairs, right operand's flag resets."""
+    fa, va = a
+    fb, vb = b
+    return fa | fb, jnp.where(fb, vb, va + vb)
+
+
 def isotonic_decreasing_parallel(y: jax.Array) -> jax.Array:
     """Project onto the non-increasing cone by parallel block merging.
 
@@ -96,7 +104,6 @@ def isotonic_decreasing_parallel(y: jax.Array) -> jax.Array:
     p = y.shape[0]
     dtype = y.dtype
     idx = jnp.arange(p)
-    S = jnp.concatenate([jnp.zeros((1,), dtype), jnp.cumsum(y)])
 
     def block_means(start):
         # scatter-free segment means: a block is [begin, end) where begin is
@@ -106,7 +113,11 @@ def isotonic_decreasing_parallel(y: jax.Array) -> jax.Array:
         begin = lax.cummax(jnp.where(start, idx, 0))
         nxt = lax.cummin(jnp.where(start, idx, p), reverse=True)
         end = jnp.concatenate([nxt[1:], jnp.full((1,), p, idx.dtype)])
-        return (S[end] - S[begin]) / (end - begin).astype(dtype)
+        # block sums from a prefix sum that restarts at every block start:
+        # differences of ONE global cumsum lose everything below eps·|Σy|,
+        # which in f32 turns zero-clipped blocks into spurious nonzeros
+        _, seg = lax.associative_scan(_segmented_add, (start, y))
+        return seg[end - 1] / (end - begin).astype(dtype)
 
     def violations(start):
         mean = block_means(start)
@@ -176,7 +187,8 @@ def prox_sorted_l1_with_norm(v: jax.Array, lam: jax.Array, *,
     }[method](w)
     x_sorted = jnp.maximum(iso, 0)
     x = jnp.zeros_like(v).at[order].set(x_sorted)
-    return (sign * x).reshape(shape), jnp.dot(x_sorted, lam)
+    return ((sign * x).reshape(shape),
+            jnp.dot(x_sorted, lam, precision=lax.Precision.HIGHEST))
 
 
 @functools.partial(jax.jit, static_argnames=("method",))

@@ -1,27 +1,23 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Responsibilities: pad to block multiples, pick interpret mode, fall back to
-the pure-jnp oracle where a kernel's preconditions don't hold (e.g. prox
-pooling beyond the VMEM budget, or a block-compacted call whose mask is a
-tracer), and — for the ``*_compact`` wrappers — build the live-block index
-list on the host and record per-call live-block telemetry
-(:func:`compact_gemv_stats`) so tests and benchmarks can assert that the
-remapped grid covers exactly the live blocks.
+Responsibilities: pad to block multiples, pick interpret mode, fall back
+where a kernel's preconditions don't hold (prox pooling beyond the SMEM
+budget, or a block-compacted call whose mask is a tracer), and — for the
+``*_compact`` wrappers — build the live-block index list on the host and
+record per-call live-block telemetry (:func:`compact_gemv_stats`) so tests
+and benchmarks can assert that the remapped grid covers exactly the live
+blocks.  Every fallback is counted in :data:`COMPACT_METRICS`
+(``fallbacks{op, reason}``, once per trace), so none is silent.
 
-Interpret mode: Pallas TPU kernels execute via the interpreter on CPU —
-that is how this container validates them; on a real TPU
-``interpret=False`` compiles to Mosaic.  The ``REPRO_PALLAS_INTERPRET``
-environment variable overrides the backend sniff (``1``/``true`` forces
-the interpreter even on TPU — useful to bisect Mosaic lowering bugs;
-``0``/``false`` forces compiled mode).  It is read at trace time, so flip
-it before the first call of a given shape.
+Interpret mode: the kernels run in the Pallas interpreter exactly when the
+backend is not a TPU (that is how the CPU test suite validates them); on
+a TPU they always compile with Mosaic.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import threading
 
 import numpy as np
@@ -31,8 +27,8 @@ import jax.numpy as jnp
 
 from ..obs import MetricsRegistry
 from . import ref as _ref
-from .prox_sorted_l1 import VMEM_ELEM_LIMIT, prox_pool_kernel_call
-from .screen_scan import DEFAULT_BLOCK, screen_scan_kernel_call
+from .prox_sorted_l1 import SMEM_ELEM_LIMIT, prox_pool_kernel_call
+from .screen_scan import DEFAULT_BLOCK, LANES, screen_scan_kernel_call
 from .slope_gemv import (
     DEFAULT_BN,
     DEFAULT_BP,
@@ -71,9 +67,6 @@ __all__ = [
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if env:  # empty (or unset) falls through to the backend sniff
-        return env not in ("0", "false", "no")
     return jax.default_backend() != "tpu"
 
 
@@ -207,7 +200,7 @@ def slope_gradient_replicate(X, R, W, *, bn: int = DEFAULT_BN,
     bp_ = min(bp, _round_up(p, 128))
     Xp = _pad_to(_pad_to(X, bn_, 0), bp_, 1)
     Rp = _pad_to(_pad_to(R3, bn_, 1), 128, 2)
-    Wt = _pad_to(W.astype(X.dtype).T, bn_, 0)  # (n, B), padded rows w = 0
+    Wt = _pad_to(W.astype(X.dtype)[..., None], bn_, 1)  # padded rows w = 0
     out = xt_matmul_replicate(Xp, Rp, Wt, bn=bn_, bp=bp_,
                               interpret=_interpret())
     out = out[:, :p, :m]
@@ -240,7 +233,7 @@ def slope_residual_replicate(X, B, Y, W, *, family: str = "none",
     Xp = _pad_to(_pad_to(X, bn_, 0), bp_, 1)
     Bp = _pad_to(_pad_to(B3, bp_, 1), 128, 2)
     Yp = _pad_to(_pad_to(Y3, bn_, 1), 128, 2)
-    Wt = _pad_to(W.astype(X.dtype).T, bn_, 0)
+    Wt = _pad_to(W.astype(X.dtype)[..., None], bn_, 1)
     out = xb_residual_replicate(Xp, Bp, Yp, Wt, family=family, m_actual=m,
                                 bn=bn_, bp=bp_, interpret=_interpret())
     out = out[:, :n, :m]
@@ -271,7 +264,7 @@ def slope_loss_residual_replicate(X, B, Y, W, *, family: str = "none",
     Xp = _pad_to(_pad_to(X, bn_, 0), bp_, 1)
     Bp = _pad_to(_pad_to(B3, bp_, 1), 128, 2)
     Yp = _pad_to(_pad_to(Y3, bn_, 1), 128, 2)
-    Wt = _pad_to(W.astype(X.dtype).T, bn_, 0)
+    Wt = _pad_to(W.astype(X.dtype)[..., None], bn_, 1)
     r, rows = xb_loss_residual_replicate(
         Xp, Bp, Yp, Wt, family=family, m_actual=m, bn=bn_, bp=bp_,
         interpret=_interpret())
@@ -310,6 +303,12 @@ _COMPACT_TELEMETRY = threading.local()
 # by op) — the aggregate view the serving stack's exporters can dump; the
 # thread-local table above stays the per-dispatch assertion surface
 COMPACT_METRICS = MetricsRegistry("kernels.compact")
+
+
+def _record_fallback(op: str, reason: str) -> None:
+    """Count one fallback off a kernel (at trace time: once per compile of
+    the calling program, not per execution)."""
+    COMPACT_METRICS.inc("fallbacks", op=op, reason=reason)
 
 
 def _record_compact(op: str, stats: "CompactGemvStats") -> None:
@@ -359,7 +358,7 @@ def slope_gradient_compact(X, R, mask, *, bn: int = DEFAULT_BN,
     """∇f = (X ⊙ mask)ᵀ R with dead column blocks never DMA'd.
 
     The live-block list is built host-side from ``mask`` (which must be
-    concrete; a traced mask silently degrades to
+    concrete; a traced mask degrades, counted, to
     :func:`slope_gradient_masked` — same results, block-skip without the
     bandwidth saving) and remaps the Pallas grid via scalar prefetch, so
     a working set of W columns streams ⌈W/bp⌉ blocks of X instead of p/bp.
@@ -373,6 +372,7 @@ def slope_gradient_compact(X, R, mask, *, bn: int = DEFAULT_BN,
         return out[:, 0] if squeeze else out
     mask_np = _concrete_mask(mask)
     if mask_np is None:
+        _record_fallback("gradient", "traced_mask")
         return slope_gradient_masked(X, R, mask, bn=bn, bp=bp)
     n, p = X.shape
     bn_ = min(bn, _round_up(n, 8))
@@ -415,6 +415,7 @@ def slope_residual_compact(X, B, Y, mask, *, family: str = "none",
         return out[:, 0] if squeeze else out
     mask_np = _concrete_mask(mask)
     if mask_np is None:
+        _record_fallback("residual", "traced_mask")
         return slope_residual_masked(X, B, Y, mask, family=family, bn=bn,
                                      bp=bp)
     n, p = X.shape
@@ -461,6 +462,7 @@ def slope_loss_residual_compact(X, B, Y, mask, *, family: str = "none",
         return jnp.sum(rows), (r[:, 0] if squeeze else r)
     mask_np = _concrete_mask(mask)
     if mask_np is None:
+        _record_fallback("loss_residual", "traced_mask")
         r, rows = _ref.xb_loss_residual_compact_ref(X, B2, Y2, mask, family)
         return jnp.sum(rows), (r[:, 0] if squeeze else r)
     n, p = X.shape
@@ -531,7 +533,8 @@ def screen_scan(c, lam, *, block: int = DEFAULT_BLOCK, use_kernel: bool = True):
     if not use_kernel:
         return _ref.screen_scan_ref(c, lam)
     (p,) = c.shape
-    blk = min(block, _round_up(p, 128))
+    tile = 8 * LANES  # a block is a whole number of (8, 128) tiles
+    blk = _round_up(min(block, _round_up(p, tile)), tile)
     # pad with c − λ = −1: strictly decreasing tail can never host the
     # rightmost argmax, so k is unaffected
     cp = _pad_to(c.astype(jnp.float32), blk, 0, value=-1.0)
@@ -542,7 +545,10 @@ def screen_scan(c, lam, *, block: int = DEFAULT_BLOCK, use_kernel: bool = True):
 @functools.partial(jax.jit, static_argnames=("use_kernel",))
 def prox_pool(w, *, use_kernel: bool = True):
     """Non-increasing isotonic projection + clip at 0."""
-    if not use_kernel or w.shape[0] > VMEM_ELEM_LIMIT:
+    if not use_kernel:
+        return _ref.prox_pool_ref(w)
+    if w.shape[0] > SMEM_ELEM_LIMIT:
+        _record_fallback("prox_pool", "smem_limit")
         return _ref.prox_pool_ref(w)
     return prox_pool_kernel_call(w, interpret=_interpret())
 

@@ -2,13 +2,13 @@
 
 The prox (FastProxSL1) is sort → subtract λ → PAVA (non-increasing) → clip.
 The sort stays in XLA (`jax.lax.sort` is already systolic-sort optimal on
-TPU); this kernel keeps the PAVA pooling entirely VMEM-resident: input,
-block stack (sums/counts) and output never touch HBM between passes.  PAVA
-is inherently sequential (each push may pool with earlier blocks), so the
-kernel is a single-program scan — its value on TPU is locality, not
-parallelism; we document this honestly and bound applicability to
-p ≤ ~5·10⁵ f32 (VMEM).  ops.py falls back to the lax.while_loop version
-beyond that.
+TPU); this kernel keeps the PAVA pooling entirely on-core: input, block
+stack (sums/counts) and output sit in scalar memory (SMEM), because every
+access is one element at a dynamic index.  PAVA is inherently sequential
+(each push may pool with earlier blocks), so the kernel is a
+single-program scan — its value on TPU is locality, not parallelism — and
+SMEM bounds it to p ≤ SMEM_ELEM_LIMIT.  ops.py falls back to the pure-jnp
+oracle beyond that, and counts the fallback.
 
 Implementation note: ``lax.while_loop`` *cond* functions must not read Refs
 (state discharge evaluates them against a snapshot), so both loops carry a
@@ -26,17 +26,22 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["prox_pool_kernel_call", "VMEM_ELEM_LIMIT"]
+from .mosaic import x32
 
-VMEM_ELEM_LIMIT = 512 * 1024
+__all__ = ["prox_pool_kernel_call", "SMEM_ELEM_LIMIT"]
+
+# input, output, block sums and counts all live in the core's 1 MiB scalar
+# memory (SMEM), the only memory a TPU kernel can index one element at a
+# time: four p-length f32 buffers fit up to p = 32768
+SMEM_ELEM_LIMIT = 32 * 1024
 
 
 def _load1(ref, i):
-    return pl.load(ref, (pl.ds(i, 1),))[0]
+    return ref[i]
 
 
 def _store1(ref, i, val, dtype=jnp.float32):
-    pl.store(ref, (pl.ds(i, 1),), jnp.full((1,), val, dtype))
+    ref[i] = jnp.asarray(val, dtype)
 
 
 def _prox_pool_kernel(w_ref, o_ref, sums_ref, counts_ref):
@@ -93,18 +98,19 @@ def _prox_pool_kernel(w_ref, o_ref, sums_ref, counts_ref):
     lax.fori_loop(0, p, emit, (0, 0))
 
 
+@x32
 def prox_pool_kernel_call(w: jax.Array, *, interpret: bool = False) -> jax.Array:
     """Non-increasing isotonic projection of ``w`` clipped at 0."""
     (p,) = w.shape
     return pl.pallas_call(
         _prox_pool_kernel,
         grid=(1,),
-        in_specs=[pl.BlockSpec((p,), lambda _: (0,))],
-        out_specs=pl.BlockSpec((p,), lambda _: (0,)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((p,), w.dtype),
         scratch_shapes=[
-            pltpu.VMEM((p,), jnp.float32),
-            pltpu.VMEM((p,), jnp.float32),
+            pltpu.SMEM((p,), jnp.float32),
+            pltpu.SMEM((p,), jnp.float32),
         ],
         interpret=interpret,
     )(w)

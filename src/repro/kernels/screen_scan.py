@@ -2,8 +2,8 @@
 
 Uses the closed form derived in DESIGN.md §1: with s = cumsum(c − λ),
 k = rightmost argmax of s when max(s) ≥ 0, else 0.  The kernel streams
-(c, λ) through VMEM in blocks, carrying three scalars across the sequential
-TPU grid: the running total of (c − λ), the best (rightmost-max) cumsum
+(c, λ) through VMEM in blocks of (rows × 128) tiles, carrying three scalars
+across the sequential TPU grid: the running total of (c − λ), the best (rightmost-max) cumsum
 value, and its global index.  One pass, O(p) HBM traffic — the screen is
 bandwidth-bound by construction, matching the paper's "cheaper than one
 gradient step" claim.
@@ -19,14 +19,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["screen_scan_kernel_call", "DEFAULT_BLOCK"]
+from .mosaic import x32
+
+__all__ = ["screen_scan_kernel_call", "DEFAULT_BLOCK", "LANES"]
 
 DEFAULT_BLOCK = 2048
 
 
+LANES = 128
+
+
+def _inclusive_scan(x, axis):
+    """Inclusive prefix sum along ``axis`` in log₂(len) shifted adds — the
+    TPU kernel compiler has no cumsum, but it rotates vectors natively."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    shift = 1
+    while shift < x.shape[axis]:
+        x = x + jnp.where(pos >= shift, pltpu.roll(x, shift, axis), 0.0)
+        shift *= 2
+    return x
+
+
 def _screen_kernel(c_ref, lam_ref, o_ref, total_ref, best_ref, idx_ref):
     b = pl.program_id(0)
-    bp = c_ref.shape[0]
+    rows, lanes = c_ref.shape  # one block = rows × 128, row-major
 
     @pl.when(b == 0)
     def _init():
@@ -37,13 +53,18 @@ def _screen_kernel(c_ref, lam_ref, o_ref, total_ref, best_ref, idx_ref):
         idx_ref[0] = jnp.int32(0)
 
     d = c_ref[...].astype(jnp.float32) - lam_ref[...].astype(jnp.float32)
-    s = jnp.cumsum(d) + total_ref[0]
+    # block-local prefix sums in row-major order: within each row, then
+    # the running total of the rows above
+    in_row = _inclusive_scan(d, 1)
+    row_sum = jnp.broadcast_to(in_row[:, lanes - 1:], in_row.shape)
+    s = in_row + (_inclusive_scan(row_sum, 0) - row_sum) + total_ref[0]
 
-    # rightmost local argmax: first max of the reversed prefix sums
-    rev = s[::-1]
-    j = jnp.argmax(rev)
-    local_best = rev[j]
-    local_idx = b * bp + (bp - 1 - j.astype(jnp.int32))
+    # rightmost local argmax: the largest position holding the maximum
+    local_best = jnp.max(s)
+    pos = (b * rows * lanes
+           + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) * lanes
+           + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    local_idx = jnp.max(jnp.where(s == local_best, pos, -1))
 
     better = local_best >= best_ref[0]  # ≥ keeps the *rightmost* on ties
     best_ref[0] = jnp.where(better, local_best, best_ref[0])
@@ -56,20 +77,21 @@ def _screen_kernel(c_ref, lam_ref, o_ref, total_ref, best_ref, idx_ref):
         o_ref[0] = k.astype(jnp.int32)
 
 
+@x32
 def screen_scan_kernel_call(
     c: jax.Array, lam: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool = False
 ) -> jax.Array:
-    """k for pre-padded inputs (length divisible by ``block``)."""
+    """k for pre-padded inputs (length divisible by ``block``, and
+    ``block`` by 8·128 — one block is a whole number of (8, 128) tiles)."""
     (p,) = c.shape
-    assert p % block == 0, (p, block)
+    assert p % block == 0 and block % (8 * LANES) == 0, (p, block)
+    rows = block // LANES
+    spec = pl.BlockSpec((rows, LANES), lambda b: (b, 0))
     return pl.pallas_call(
         _screen_kernel,
         grid=(p // block,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda b: (b,)),
-            pl.BlockSpec((block,), lambda b: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda b: (0,)),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.int32),
         scratch_shapes=[
             pltpu.SMEM((1,), jnp.float32),
@@ -77,4 +99,4 @@ def screen_scan_kernel_call(
             pltpu.SMEM((1,), jnp.int32),
         ],
         interpret=interpret,
-    )(c, lam)[0]
+    )(c.reshape(-1, LANES), lam.reshape(-1, LANES))[0]

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mosaic import x32
+
 __all__ = [
     "xt_matmul",
     "xt_matmul_masked",
@@ -61,6 +63,8 @@ __all__ = [
 
 DEFAULT_BN = 256
 DEFAULT_BP = 512
+# f32 operands get the full f32 product: the TPU default is one bf16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _xt_matmul_kernel(x_ref, r_ref, o_ref, acc_ref):
@@ -75,6 +79,7 @@ def _xt_matmul_kernel(x_ref, r_ref, o_ref, acc_ref):
         r_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),  # Xᵀ·R without transpose copy
         preferred_element_type=jnp.float32,
+        precision=_HIGHEST,
     )
 
     @pl.when(nb == pl.num_programs(1) - 1)
@@ -82,6 +87,7 @@ def _xt_matmul_kernel(x_ref, r_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+@x32
 def xt_matmul(
     X: jax.Array,
     R: jax.Array,
@@ -120,13 +126,14 @@ def _xt_matmul_masked_kernel(x_ref, r_ref, mask_ref, o_ref, acc_ref):
     # per-block summary: a fully-masked (bn × bp) block contributes nothing,
     # so its MXU pass is skipped outright (the strong rule typically leaves
     # W ≪ p columns alive → ⌈W/bp⌉ blocks of compute instead of p/bp)
-    @pl.when(jnp.any(mb > 0))
+    @pl.when(jnp.max(mb) > 0)
     def _acc():
         acc_ref[...] += jax.lax.dot_general(
             x_ref[...] * mb,  # zero masked columns inside kept blocks
             r_ref[...],
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=_HIGHEST,
         )
 
     @pl.when(nb == pl.num_programs(1) - 1)
@@ -134,6 +141,7 @@ def _xt_matmul_masked_kernel(x_ref, r_ref, mask_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+@x32
 def xt_matmul_masked(
     X: jax.Array,
     R: jax.Array,
@@ -193,7 +201,9 @@ def _xb_residual_kernel(x_ref, b_ref, y_ref, o_ref, acc_ref, *, family, m_actual
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(x_ref[...], b_ref[...],
+                            preferred_element_type=jnp.float32,
+                            precision=_HIGHEST)
 
     @pl.when(pb == pl.num_programs(1) - 1)
     def _flush():
@@ -203,6 +213,7 @@ def _xb_residual_kernel(x_ref, b_ref, y_ref, o_ref, acc_ref, *, family, m_actual
         )
 
 
+@x32
 def xb_residual(
     X: jax.Array,
     B: jax.Array,
@@ -246,10 +257,11 @@ def _xb_residual_masked_kernel(x_ref, b_ref, y_ref, mask_ref, o_ref, acc_ref,
 
     mb = mask_ref[...]  # (1, bp)
 
-    @pl.when(jnp.any(mb > 0))
+    @pl.when(jnp.max(mb) > 0)
     def _acc():
         acc_ref[...] += jnp.dot(x_ref[...] * mb, b_ref[...],
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=_HIGHEST)
 
     @pl.when(pb == pl.num_programs(1) - 1)
     def _flush():
@@ -258,6 +270,7 @@ def _xb_residual_masked_kernel(x_ref, b_ref, y_ref, mask_ref, o_ref, acc_ref,
                                m_actual).astype(o_ref.dtype)
 
 
+@x32
 def xb_residual_masked(
     X: jax.Array,
     B: jax.Array,
@@ -317,6 +330,7 @@ def _xt_matmul_compact_kernel(live_ref, x_ref, r_ref, mask_ref, o_ref,
         r_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=_HIGHEST,
     )
 
     @pl.when(nb == pl.num_programs(1) - 1)
@@ -324,6 +338,7 @@ def _xt_matmul_compact_kernel(live_ref, x_ref, r_ref, mask_ref, o_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+@x32
 def xt_matmul_compact(
     X: jax.Array,
     R: jax.Array,
@@ -379,7 +394,8 @@ def _xb_residual_compact_kernel(live_ref, x_ref, b_ref, y_ref, mask_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...] * mask_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_HIGHEST)
 
     @pl.when(pb == pl.num_programs(1) - 1)
     def _flush():
@@ -388,6 +404,7 @@ def _xb_residual_compact_kernel(live_ref, x_ref, b_ref, y_ref, mask_ref,
                                m_actual).astype(o_ref.dtype)
 
 
+@x32
 def xb_residual_compact(
     X: jax.Array,
     B: jax.Array,
@@ -449,7 +466,8 @@ def _xb_loss_residual_compact_kernel(live_ref, x_ref, b_ref, y_ref, mask_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...] * mask_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_HIGHEST)
 
     @pl.when(pb == pl.num_programs(1) - 1)
     def _flush():
@@ -461,6 +479,7 @@ def _xb_loss_residual_compact_kernel(live_ref, x_ref, b_ref, y_ref, mask_ref,
                                          loss_ref.shape).astype(loss_ref.dtype)
 
 
+@x32
 def xb_loss_residual_compact(
     X: jax.Array,
     B: jax.Array,
@@ -527,8 +546,10 @@ def xb_loss_residual_compact(
 # — the X operand is the SAME array for every member.  These kernels put the
 # member axis on the grid and give X a BlockSpec index map that ignores it,
 # so X is held once in HBM (O(n·p), not O(B·n·p)) while the per-member
-# operands stay O(B·n).  Weights ride in transposed as (n, B) so a member's
-# slice is a clean (bn, 1) column block broadcasting against (bn, m) tiles.
+# operands stay O(B·n).  Weights ride in as (B, n, 1) so a member's slice
+# is a (bn, 1) column block broadcasting against (bn, m) tiles — the
+# trailing unit axis keeps the block's last dimension equal to the
+# array's, which the TPU block tiling rules require.
 # Zero-weight rows are where-guarded to an exact 0 (the same guard as
 # ``Family.weighted_residual``), so a w = 0 row can never leak a non-finite
 # residual into the sums — and so results are bit-identical to applying the
@@ -549,9 +570,10 @@ def _xt_matmul_replicate_kernel(x_ref, r_ref, w_ref, o_ref, acc_ref):
 
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...],
-        _apply_w(w_ref[...], r_ref[0]),
+        _apply_w(w_ref[0], r_ref[0]),
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=_HIGHEST,
     )
 
     @pl.when(nb == pl.num_programs(2) - 1)
@@ -559,6 +581,7 @@ def _xt_matmul_replicate_kernel(x_ref, r_ref, w_ref, o_ref, acc_ref):
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
+@x32
 def xt_matmul_replicate(
     X: jax.Array,
     R: jax.Array,
@@ -570,15 +593,15 @@ def xt_matmul_replicate(
 ) -> jax.Array:
     """G_b = Xᵀ (w_b ⊙ R_b) for all B members against one shared X.
 
-    Shapes: X (n, p) shared, R (B, n, m) per-member residuals, W (n, B)
-    transposed row weights → G (B, p, m).  Per member the block schedule
+    Shapes: X (n, p) shared, R (B, n, m) per-member residuals, W (B, n, 1)
+    row weights → G (B, p, m).  Per member the block schedule
     (and therefore every partial sum) is exactly :func:`xt_matmul`'s on the
     pre-weighted residual, so results are bit-identical to the materialized
     reference.  Caller pads n/p to blocks.
     """
     n, p = X.shape
     B, n_r, m = R.shape
-    assert n_r == n and W.shape == (n, B), (X.shape, R.shape, W.shape)
+    assert n_r == n and W.shape == (B, n, 1), (X.shape, R.shape, W.shape)
     assert n % bn == 0 and p % bp == 0, (n, p, bn, bp)
     grid = (B, p // bp, n // bn)
     return pl.pallas_call(
@@ -587,7 +610,7 @@ def xt_matmul_replicate(
         in_specs=[
             pl.BlockSpec((bn, bp), lambda b, pb, nb: (nb, pb)),  # shared X
             pl.BlockSpec((1, bn, m), lambda b, pb, nb: (b, nb, 0)),
-            pl.BlockSpec((bn, 1), lambda b, pb, nb: (nb, b)),
+            pl.BlockSpec((1, bn, 1), lambda b, pb, nb: (b, nb, 0)),
         ],
         out_specs=pl.BlockSpec((1, bp, m), lambda b, pb, nb: (b, pb, 0)),
         out_shape=jax.ShapeDtypeStruct((B, p, m), X.dtype),
@@ -605,7 +628,8 @@ def _xb_residual_replicate_kernel(x_ref, b_ref, y_ref, w_ref, o_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...], b_ref[0],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_HIGHEST)
 
     @pl.when(pb == pl.num_programs(2) - 1)
     def _flush():
@@ -615,9 +639,10 @@ def _xb_residual_replicate_kernel(x_ref, b_ref, y_ref, w_ref, o_ref, acc_ref,
         # output (w stays in its native dtype, as it would host-side)
         r = _epilogue(z, y_ref[0].astype(jnp.float32), family,
                       m_actual).astype(o_ref.dtype)
-        o_ref[0] = _apply_w(w_ref[...], r)
+        o_ref[0] = _apply_w(w_ref[0], r)
 
 
+@x32
 def xb_residual_replicate(
     X: jax.Array,
     B: jax.Array,
@@ -634,12 +659,12 @@ def xb_residual_replicate(
 
     Shapes: X (n, p), B (Bm, p, m) per-member coefficients, Y (Bm, n, m)
     per-member responses (permutation replicates differ per member; others
-    broadcast), W (n, Bm) → r (Bm, n, m) already weighted for the gradient
-    matvec.
+    broadcast), W (Bm, n, 1) → r (Bm, n, m) already weighted for the
+    gradient matvec.
     """
     n, p = X.shape
     Bm, p_b, m = B.shape
-    assert p_b == p and Y.shape == (Bm, n, m) and W.shape == (n, Bm), (
+    assert p_b == p and Y.shape == (Bm, n, m) and W.shape == (Bm, n, 1), (
         X.shape, B.shape, Y.shape, W.shape)
     assert n % bn == 0 and p % bp == 0, (n, p, bn, bp)
     m_actual = m if m_actual is None else m_actual
@@ -653,7 +678,7 @@ def xb_residual_replicate(
             pl.BlockSpec((bn, bp), lambda b, nb, pb: (nb, pb)),  # shared X
             pl.BlockSpec((1, bp, m), lambda b, nb, pb: (b, pb, 0)),
             pl.BlockSpec((1, bn, m), lambda b, nb, pb: (b, nb, 0)),
-            pl.BlockSpec((bn, 1), lambda b, nb, pb: (nb, b)),
+            pl.BlockSpec((1, bn, 1), lambda b, nb, pb: (b, nb, 0)),
         ],
         out_specs=pl.BlockSpec((1, bn, m), lambda b, nb, pb: (b, nb, 0)),
         out_shape=jax.ShapeDtypeStruct((Bm, n, m), X.dtype),
@@ -672,13 +697,14 @@ def _xb_loss_residual_replicate_kernel(x_ref, b_ref, y_ref, w_ref, r_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...], b_ref[0],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_HIGHEST)
 
     @pl.when(pb == pl.num_programs(2) - 1)
     def _flush():
         z = acc_ref[...]
         y = y_ref[0].astype(jnp.float32)
-        w = w_ref[...]
+        w = w_ref[0]
         # epilogue → output dtype first, then native-dtype weighting: bit-
         # identical to host-weighting the unweighted kernel's outputs
         r = _epilogue(z, y, family, m_actual).astype(r_ref.dtype)
@@ -689,6 +715,7 @@ def _xb_loss_residual_replicate_kernel(x_ref, b_ref, y_ref, w_ref, r_ref,
             loss_ref.shape[1:]).astype(loss_ref.dtype)
 
 
+@x32
 def xb_loss_residual_replicate(
     X: jax.Array,
     B: jax.Array,
@@ -711,7 +738,7 @@ def xb_loss_residual_replicate(
     """
     n, p = X.shape
     Bm, p_b, m = B.shape
-    assert p_b == p and Y.shape == (Bm, n, m) and W.shape == (n, Bm), (
+    assert p_b == p and Y.shape == (Bm, n, m) and W.shape == (Bm, n, 1), (
         X.shape, B.shape, Y.shape, W.shape)
     assert n % bn == 0 and p % bp == 0, (n, p, bn, bp)
     m_actual = m if m_actual is None else m_actual
@@ -725,7 +752,7 @@ def xb_loss_residual_replicate(
             pl.BlockSpec((bn, bp), lambda b, nb, pb: (nb, pb)),  # shared X
             pl.BlockSpec((1, bp, m), lambda b, nb, pb: (b, pb, 0)),
             pl.BlockSpec((1, bn, m), lambda b, nb, pb: (b, nb, 0)),
-            pl.BlockSpec((bn, 1), lambda b, nb, pb: (nb, b)),
+            pl.BlockSpec((1, bn, 1), lambda b, nb, pb: (b, nb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bn, m), lambda b, nb, pb: (b, nb, 0)),
@@ -774,7 +801,8 @@ def _xb_loss_residual_kernel(x_ref, b_ref, y_ref, r_ref, loss_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_HIGHEST)
 
     @pl.when(pb == pl.num_programs(1) - 1)
     def _flush():
@@ -786,6 +814,7 @@ def _xb_loss_residual_kernel(x_ref, b_ref, y_ref, r_ref, loss_ref, acc_ref,
                                          loss_ref.shape).astype(loss_ref.dtype)
 
 
+@x32
 def xb_loss_residual(
     X: jax.Array,
     B: jax.Array,

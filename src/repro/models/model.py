@@ -277,7 +277,6 @@ def _embed_lookup(table, tokens, cfg: ArchConfig):
             or V % rules["mesh"].shape.get("model", 1)):
         return jnp.take(table, tokens, axis=0).astype(cfg.adtype)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rules["mesh"]
@@ -294,12 +293,12 @@ def _embed_lookup(table, tokens, cfg: ArchConfig):
         out = jnp.where(ok[..., None], out.astype(cfg.adtype), 0)
         return jax.lax.psum(out, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", None), P(bspec, None)),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(table, tokens)
 
 

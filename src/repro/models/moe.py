@@ -124,7 +124,6 @@ def moe_layer(x, p, cfg: ArchConfig, *, mesh=None) -> tuple[jax.Array, jax.Array
         E_pad = ((m.n_experts + M - 1) // M) * M
         E_loc = E_pad // M
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -155,12 +154,12 @@ def moe_layer(x, p, cfg: ArchConfig, *, mesh=None) -> tuple[jax.Array, jax.Array
             experts = jax.tree.map(
                 lambda w: jnp.concatenate([w, jnp.zeros((pad,) + w.shape[1:], w.dtype)]), experts
             )
-        y2d, aux = shard_map(
+        y2d, aux = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(batch_axes if batch_axes else None, None), P(), P("model")),
             out_specs=(P(batch_axes if batch_axes else None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x2d, p["router"], experts)
     else:
         capacity = max(4, int(B * S * m.top_k * m.capacity_factor / m.n_experts))

@@ -43,6 +43,7 @@ import numpy as np
 
 from ..core.lambda_seq import (
     bh_sequence,
+    float_dtype,
     gaussian_sequence,
     lasso_sequence,
     oscar_sequence,
@@ -239,10 +240,14 @@ class LambdaCanonicalizer:
         self._lock = threading.Lock()
 
     def get(self, kind: str, q: float, size: int,
-            n: int | None = None) -> np.ndarray:
+            n: int | None = None, dtype=None) -> np.ndarray:
+        """The sequence in :func:`~repro.core.lambda_seq.float_dtype` of
+        ``dtype`` — the operands' dtype, JAX's default float if None."""
+        dtype = float_dtype(dtype)
         # n parameterizes only the gaussian recursion; keying every other
         # kind on it would duplicate byte-identical arrays per problem size
-        key = (kind, float(q), int(size), n if kind == "gaussian" else None)
+        key = (kind, float(q), int(size), n if kind == "gaussian" else None,
+               dtype.name)
         with self._lock:
             lam = self._memo.get(key)
             if lam is None:
@@ -252,13 +257,13 @@ class LambdaCanonicalizer:
                         f"unknown λ sequence {kind!r}; choose from "
                         f"{sorted(_SEQUENCES)}")
                 if kind == "lasso":
-                    lam = np.asarray(fn(size), np.float64)
+                    lam = np.asarray(fn(size, dtype=dtype), dtype)
                 elif kind == "gaussian":
                     if n is None:
                         raise ValueError("gaussian sequences need n")
-                    lam = np.asarray(fn(size, n, q), np.float64)
+                    lam = np.asarray(fn(size, n, q, dtype=dtype), dtype)
                 else:
-                    lam = np.asarray(fn(size, q), np.float64)
+                    lam = np.asarray(fn(size, q, dtype=dtype), dtype)
                 lam.flags.writeable = False
                 self._memo[key] = lam
             return lam

@@ -47,6 +47,8 @@ __all__ = [
     "default_policy",
     "PaddedBatch",
     "pad_batch",
+    "SERVE_SLOTS",
+    "STEP_CHUNK",
 ]
 
 _MISSING = object()
@@ -183,6 +185,14 @@ class BucketRegistry:
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return (f"BucketRegistry({self.name!r}, size={len(self)}, "
                 f"capacity={self.capacity})")
+
+
+# the default services' batch slots and continuous-batching chunk length.
+# A direct pad="bucket" fit runs the default async service's own programs
+# (these slots, these chunks): on a TPU, programs of another batch width or
+# loop structure round differently, so only the same programs are bitwise.
+SERVE_SLOTS = 8
+STEP_CHUNK = 8
 
 
 @dataclasses.dataclass(frozen=True)

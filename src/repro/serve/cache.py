@@ -26,6 +26,7 @@ import numpy as np
 
 import jax
 
+from ..core.lambda_seq import float_dtype
 from ..core.losses import Family
 from ..obs import MetricsRegistry
 from ..obs.profile import annotate
@@ -69,12 +70,16 @@ class ProgramSpec:
     max_refits: int = 32
     working_set: int | None = None
     working_set_top: int | None = None
-    dtype: str = "float64"
-    y_dtype: str = "float64"
+    dtype: str | None = None
+    y_dtype: str | None = None
     variant: str = "path"
     step_chunk: int | None = None
 
     def __post_init__(self):
+        # unset dtypes are JAX's default float: f64 only under x64
+        for f in ("dtype", "y_dtype"):
+            if getattr(self, f) is None:
+                object.__setattr__(self, f, float_dtype().name)
         if self.variant not in ("path", "chunk", "init", "replicate"):
             raise ValueError(f"variant must be 'path', 'chunk', 'init' or "
                              f"'replicate', got {self.variant!r}")

@@ -75,7 +75,7 @@ from ..core.losses import Family, ols
 from ..core.path import _stop_triggered
 from ..core.solver import DEFAULT_WS_TIERS
 from .batcher import Pending, QueueFull, Rejection
-from .buckets import pad_batch
+from .buckets import SERVE_SLOTS, STEP_CHUNK, pad_batch
 from .cache import ProgramSpec
 from .durable import (
     InflightSlot,
@@ -132,8 +132,9 @@ class AsyncPathService(PathService):
     testing admission without execution); :meth:`start` launches it.
     """
 
-    def __init__(self, *, max_batch: int = 8, max_delay: float = 0.02,
-                 step_chunk: int = 8, max_queue: int | None = 64,
+    def __init__(self, *, max_batch: int = SERVE_SLOTS,
+                 max_delay: float = 0.02, step_chunk: int = STEP_CHUNK,
+                 max_queue: int | None = 64,
                  retry_limit: int = 2, retry_backoff: float = 0.02,
                  retry_jitter: float = 0.25,
                  autostart: bool = True, policy=None, cache=None,
@@ -1062,7 +1063,8 @@ class AsyncPathService(PathService):
                max_refits: int = 32,
                working_set: int | str | None = None,
                ws_tiers: int | str = DEFAULT_WS_TIERS,
-               dtype: str = "float64", y_dtype: str = "float64") -> dict:
+               dtype: str | None = None,
+               y_dtype: str | None = None) -> dict:
         """Pre-compile what async serving actually runs: the (init, chunk)
         program pair for masked shapes; compact shapes defer to the base
         whole-grid warmup."""

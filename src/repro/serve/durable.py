@@ -103,20 +103,13 @@ def _spec_from_json(d: dict):
 
 def backend_fingerprint() -> str:
     """What a serialized executable's validity depends on: jax + jaxlib
-    versions and the backend it was compiled for."""
+    versions, the backend and the device kind it was compiled for (a TPU
+    executable built for one chip generation never loads on another)."""
     import jaxlib
 
     return (f"jax={jax.__version__}|jaxlib={jaxlib.__version__}"
-            f"|backend={jax.default_backend()}")
-
-
-def _can_serialize() -> bool:
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-
-        return True
-    except ImportError:  # pragma: no cover - backend-dependent
-        return False
+            f"|backend={jax.default_backend()}"
+            f"|device={jax.devices()[0].device_kind}")
 
 
 class DurableProgramStore:
@@ -125,29 +118,22 @@ class DurableProgramStore:
 
     ``save``/``load`` serialize through
     :mod:`jax.experimental.serialize_executable` (true skip-compile
-    restore).  When that is unavailable on the backend, the store degrades
-    to wiring :mod:`jax.experimental.compilation_cache` at ``path`` — XLA
-    then persists compilation artifacts itself and re-``lower().compile()``
-    calls hit that cache; ``load`` returns None so callers rebuild (fast
-    against the warmed XLA cache), and the manifest still drives boot
-    warmup.  Integrity: every entry stores the spec token, the
-    jax/jaxlib/backend fingerprint and a payload checksum; any mismatch or
-    unpickling error discards the entry (counted, file unlinked) — a
-    corrupt store can cost a rebuild, never a wrong program.
+    restore).  A program the backend refuses to serialize is counted
+    (``unserializable``) and left to rebuild on the next boot; the
+    manifest still records it.  Integrity: every entry stores the spec
+    token, the jax/jaxlib/backend/device fingerprint and a payload
+    checksum; any mismatch or unpickling error discards the entry
+    (counted, file unlinked) — a corrupt store can cost a rebuild, never a
+    wrong program.  The store places no XLA compilation cache: that is the
+    entry point's business (``repro.compile_cache``).
     """
 
     def __init__(self, path: str | os.PathLike):
         self.path = os.fspath(path)
         os.makedirs(self.path, exist_ok=True)
-        self.serializable = _can_serialize()
-        if not self.serializable:  # pragma: no cover - backend-dependent
-            from jax.experimental import compilation_cache
-
-            compilation_cache.set_cache_dir(
-                os.path.join(self.path, "xla_cache"))
         self._lock = threading.Lock()
         self.counters = {"saved": 0, "loaded": 0, "discarded": 0,
-                         "replayed": 0}
+                         "replayed": 0, "recompiled": 0, "unserializable": 0}
 
     # -- keying -------------------------------------------------------------
 
@@ -166,14 +152,28 @@ class DurableProgramStore:
     def save(self, spec, prog) -> bool:
         """Serialize one :class:`~repro.serve.cache.CompiledProgram` and
         append the spec to the warmup manifest.  Returns False (and still
-        records the manifest entry) when executable serialization is
-        unavailable."""
-        self._append_manifest(spec)
-        if not self.serializable:  # pragma: no cover - backend-dependent
-            return False
+        records the manifest entry) when the backend refuses to serialize
+        the executable."""
         from jax.experimental import serialize_executable as se
 
-        payload, in_tree, out_tree = se.serialize(prog._compiled)
+        from .cache import _build
+
+        self._append_manifest(spec)
+        try:
+            payload, in_tree, out_tree = se.serialize(prog._compiled)
+        except jax.errors.JaxRuntimeError:
+            # XLA:CPU cannot serialize an executable once it has run a
+            # sort, and JAX hands every compile of one program in a process
+            # the same executable: compile this one afresh, unrun
+            jax.clear_caches()
+            with self._lock:
+                self.counters["recompiled"] += 1
+            try:
+                payload, in_tree, out_tree = se.serialize(_build(spec)[0])
+            except jax.errors.JaxRuntimeError:
+                with self._lock:
+                    self.counters["unserializable"] += 1
+                return False
         entry = {
             "version": _ENTRY_VERSION,
             "token": _spec_token(spec),
@@ -202,8 +202,6 @@ class DurableProgramStore:
         from source, which is always safe."""
         from .cache import CompiledProgram
 
-        if not self.serializable:  # pragma: no cover - backend-dependent
-            return None
         target = self._entry_path(spec)
         if not os.path.exists(target):
             return None
@@ -287,8 +285,7 @@ class DurableProgramStore:
         with self._lock:
             entries = sum(1 for f in os.listdir(self.path)
                           if f.endswith(".prog"))
-            return {"path": self.path, "entries": entries,
-                    "serializable": self.serializable, **self.counters}
+            return {"path": self.path, "entries": entries, **self.counters}
 
 
 # -- watchdog ---------------------------------------------------------------

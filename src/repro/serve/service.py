@@ -92,7 +92,7 @@ from .batcher import (
     Rejection,
     RejectionError,
 )
-from .buckets import ShapeBucketPolicy, default_policy, pad_batch
+from .buckets import SERVE_SLOTS, ShapeBucketPolicy, default_policy, pad_batch
 from .cache import ProgramCache, ProgramSpec
 from .durable import (
     CircuitBreaker,
@@ -292,7 +292,7 @@ class PathService:
     inert dummy problems.
     """
 
-    def __init__(self, *, max_batch: int = 8, max_delay: float = 0.02,
+    def __init__(self, *, max_batch: int = SERVE_SLOTS, max_delay: float = 0.02,
                  max_queue: int | None = None,
                  policy: ShapeBucketPolicy | None = None,
                  cache: ProgramCache | None = None,
@@ -458,7 +458,8 @@ class PathService:
         n, p = X.shape
         m = family.n_classes
         if lam is None:
-            lam = self.canonicalizer.get(lam_kind, lam_q, p * m, n=n)
+            lam = self.canonicalizer.get(lam_kind, lam_q, p * m, n=n,
+                                         dtype=X.dtype)
         lam = np.asarray(lam)
         if lam.shape != (p * m,):
             raise ValueError(f"lam must have p·m = {p * m} entries, got "
@@ -675,7 +676,8 @@ class PathService:
         Xw, yw = apply_weights(problem)
         m = problem.family.n_classes
         lam = as_lambda_spec(path.lam).resolve(
-            problem.p * m, n=problem.n, canonicalizer=self.canonicalizer)
+            problem.p * m, n=problem.n, canonicalizer=self.canonicalizer,
+            dtype=Xw.dtype)
         return self.submit(
             Xw, yw, family=problem.family, lam=lam, sigmas=path.sigmas,
             path_length=path.path_length, sigma_ratio=path.sigma_ratio,
@@ -748,7 +750,7 @@ class PathService:
         n, p = X.shape
         m = family.n_classes
         lam = as_lambda_spec(path.lam).resolve(
-            p * m, n=n, canonicalizer=self.canonicalizer)
+            p * m, n=n, canonicalizer=self.canonicalizer, dtype=X.dtype)
         lam = np.asarray(lam)
         if policy.validate == "strict":
             issues = find_nonfinite(X=X, y=y, lam=lam, sigmas=path.sigmas)
@@ -1133,7 +1135,8 @@ class PathService:
                max_refits: int = 32,
                working_set: int | str | None = None,
                ws_tiers: int | str = DEFAULT_WS_TIERS,
-               dtype: str = "float64", y_dtype: str = "float64") -> dict:
+               dtype: str | None = None,
+               y_dtype: str | None = None) -> dict:
         """Pre-compile the programs a list of native ``(n, p)`` shapes will
         need, so the first live request pays no XLA latency."""
         specs = []

@@ -99,8 +99,6 @@ def test_store_round_trip_skips_compile_bitwise(tmp_path):
         cold_stats = svc.stats()["cache"]
     finally:
         svc.close()
-    if not store.serializable:
-        pytest.skip("executable serialization unavailable on this backend")
     assert cold_stats["builds"] == cold_stats["misses"] > 0
     assert store.stats()["saved"] == cold_stats["builds"]
 
@@ -125,8 +123,6 @@ def test_store_discards_corrupt_and_mismatched_entries(tmp_path):
         ref = _result(svc.submit(X, y, **SVC_KW))
     finally:
         svc.close()
-    if not store.serializable:
-        pytest.skip("executable serialization unavailable on this backend")
     entries = [f for f in os.listdir(store.path) if f.endswith(".prog")]
     assert entries
 
@@ -159,10 +155,48 @@ def test_store_discards_corrupt_and_mismatched_entries(tmp_path):
     np.testing.assert_array_equal(ref.betas, again.betas)
 
 
+def test_store_saves_a_program_that_already_ran(tmp_path):
+    """XLA:CPU refuses to serialize an executable once it has run, and
+    every compile of one program in a process returns that executable:
+    the store compiles it afresh, saves it, and counts the recompile."""
+    X, y = PROBLEMS[2]
+    svc = _asvc(ProgramCache(capacity=16))  # runs the programs, no store
+    try:
+        _result(svc.submit(X, y, **SVC_KW))
+        specs = list(svc.cache._data)
+    finally:
+        svc.close()
+    store = DurableProgramStore(tmp_path / "store")
+    cache = ProgramCache(capacity=16, store=store)
+    for spec in specs:
+        cache.get(spec)
+    st = store.stats()
+    assert st["saved"] == len(specs)
+    assert st["unserializable"] == 0
+    assert st["recompiled"] <= len(specs)
+    # and what was saved loads and serves bitwise like the first run
+    svc2 = _asvc(store=DurableProgramStore(tmp_path / "store"))
+    try:
+        again = _result(svc2.submit(X, y, **SVC_KW))
+        assert svc2.stats()["cache"]["builds"] == 0
+    finally:
+        svc2.close()
+    svc3 = _asvc(ProgramCache(capacity=16))
+    try:
+        ref = _result(svc3.submit(X, y, **SVC_KW))
+    finally:
+        svc3.close()
+    np.testing.assert_array_equal(ref.betas, again.betas)
+
+
+def test_backend_fingerprint_names_the_device_kind():
+    import jax
+
+    assert f"device={jax.devices()[0].device_kind}" in backend_fingerprint()
+
+
 def test_store_load_rejects_unpicklable_garbage(tmp_path):
     store = DurableProgramStore(tmp_path / "store")
-    if not store.serializable:
-        pytest.skip("executable serialization unavailable on this backend")
     spec = ProgramSpec(family=ols, batch=1, n_rows=32, n_cols=32,
                        path_length=L, screening="strong", solver_tol=1e-10,
                        max_iter=200, kkt_tol=1e-4, max_refits=32,
@@ -187,8 +221,6 @@ def test_manifest_replay_second_boot_compiles_nothing(tmp_path):
             _result(svc.submit(X, y, **SVC_KW))
     finally:
         svc.close()
-    if not store.serializable:
-        pytest.skip("executable serialization unavailable on this backend")
     manifest = store.manifest_specs()
     assert manifest  # live traffic recorded what it compiled
 

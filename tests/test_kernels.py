@@ -224,9 +224,16 @@ def test_compact_gemv_traced_mask_degrades_to_masked(rng):
     def traced(m):
         return slope_gradient_compact(X, r, m)
 
+    from repro.kernels.ops import COMPACT_METRICS
+
+    before = COMPACT_METRICS.value("fallbacks", op="gradient",
+                                   reason="traced_mask")
     np.testing.assert_allclose(
         np.asarray(traced(mask)),
         np.asarray(slope_gradient_masked(X, r, mask)), rtol=2e-5, atol=2e-5)
+    # the fallback is counted (once, when the caller's program traces)
+    assert COMPACT_METRICS.value("fallbacks", op="gradient",
+                                 reason="traced_mask") == before + 1
 
 
 def test_gemv_1d_paths(rng):

@@ -42,6 +42,25 @@ def test_oscar_and_lasso_sequences():
     np.testing.assert_allclose(las, np.ones(7))
 
 
+@pytest.mark.parametrize("kind", ["bh", "gaussian", "oscar", "lasso"])
+def test_named_lambda_follows_design_dtype(kind):
+    """An f32 design gets an f32 λ and σ grid even under x64, so no f64
+    op enters an f32 program; f64 designs keep f64."""
+    from repro.api import LambdaSpec
+    from repro.core import ols
+    from repro.core.engine import null_sigma_grid
+
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(30, 50))
+    y = rng.normal(size=30)
+    for dt in (np.float32, np.float64):
+        lam = LambdaSpec(kind, q=0.1).resolve(50, n=30, dtype=dt)
+        assert lam.dtype == dt
+        sig = null_sigma_grid(X.astype(dt), y.astype(dt), lam, ols,
+                              path_length=5, sigma_ratio=None)
+        assert sig.dtype == dt
+
+
 def test_sigma_grid_paper_ratios():
     g1 = sigma_grid(2.0, length=10, n=50, p=100)   # n < p → ratio 1e-2
     assert g1[0] == 2.0 and np.isclose(g1[-1], 2.0 * 1e-2)

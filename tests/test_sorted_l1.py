@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import (
+    bh_sequence,
     dual_sorted_l1_gauge,
     in_subdifferential,
     isotonic_decreasing,
@@ -131,6 +132,24 @@ def test_isotonic_parallel_and_minimax_match_stack(rng):
         if p == 200:  # minimax builds p×p intermediates; one shape suffices
             np.testing.assert_allclose(np.asarray(iso_mm(jnp.asarray(y))),
                                        want, atol=1e-10)
+
+
+def test_isotonic_parallel_f32_matches_stack_at_path_width():
+    """In f32 at the paper's width the sweep-merging form keeps the stack
+    form's accuracy: block sums come from a prefix sum that restarts at each
+    block, not from differences of one global cumsum (≈1e-3 off here)."""
+    from repro.core import isotonic_decreasing_parallel
+
+    # its own stream: draws from the session rng would shift later tests'
+    rng = np.random.default_rng(12)
+    p = 20000
+    lam = np.asarray(bh_sequence(p, q=0.1), np.float64)
+    w = np.sort(np.abs(rng.normal(size=p)) * 3)[::-1] - 0.9 * lam
+    w32 = jnp.asarray(w, jnp.float32)
+    want = np.asarray(isotonic_decreasing(w32))
+    got = np.asarray(jax.jit(isotonic_decreasing_parallel)(w32))
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    np.testing.assert_array_equal(got > 0, want > 0)
 
 
 def test_norm_properties(rng):
