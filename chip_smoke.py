@@ -11,18 +11,21 @@ compile and run, and its check (any failure raises; nothing is caught):
 2. direct   — ``slope_path`` at the paper's size (n=200, p=20 000, 100-step
               BH path): OLS on an equicorrelated design, logistic on an AR
               design; every returned step passes the NumPy f64 KKT test.
-3. cv       — 10-fold OLS CV through the planner (compact engine); every
-              fold's fit and the full-data refit at the selected σ pass
-              the KKT test.
+3. cv       — 10-fold OLS CV through the planner (compact engine) on the
+              same design; every fold path past its null head (index 0,
+              β = 0 by construction) passes the KKT test on its training
+              rows, and so does the full-data refit at the selected σ.
 4. served   — ``AsyncPathService`` answers 8 OLS requests of seeded sizes
-              (n ∈ [150, 256], p ∈ [12 000, 20 000], uncorrelated design,
-              50-step paths); each response equals a direct
-              ``pad="bucket"`` fit bitwise and passes the KKT test.
+              (n ∈ [150, 256], p ∈ [12 000, 20 000], equicorrelated
+              ρ = 0.5, the top 10 points of the 100-point σ grid, run in
+              two chunks); each response equals a direct ``pad="bucket"``
+              fit bitwise and passes the KKT test.
 5. restart  — a second service boots from the durable program store phase
               4 filled, compiles nothing, and serves the same betas bitwise.
 6. kernels  — each Pallas kernel once at a real width against its
               ``repro.kernels.ref`` oracle evaluated on the host CPU.
-7. x64      — phase 2's logistic fit again with x64 on, in f64.
+7. x64      — phase 2's OLS fit over the same top 10 grid points, with
+              x64 on, in f64.
 
 Phases 2–6 run in f32 with x64 off, so no f64 op hides in them.  The last
 line of standard output is the JSON verdict the chip check reads.
@@ -42,15 +45,16 @@ import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-# the KKT certificate each returned step must meet: in_subdifferential's
+# the KKT certificate every returned step must meet: in_subdifferential's
 # tolerance is rtol·max(1, max σλ).  FISTA stops on an objective plateau
-# plus a fixed-point test (SolverPolicy defaults), not on a KKT bound, and
-# the equicorrelated design's Lipschitz constant grows like ρ·p, so the
-# certificate is coarse at p = 20 000: the gate is a gross-error bound on
-# every step plus a tighter bound on the median step.
-KKT_RTOL_EVERY = 0.25
-KKT_RTOL_MEDIAN = 1e-2
-KKT_LEVELS = (1e-3, 1e-2, 1e-1, KKT_RTOL_EVERY)
+# plus a fixed-point test (SolverPolicy defaults), not on a KKT bound: the
+# f64 path under the same policy certifies every step at rtol 1e-2, not
+# every step at 1e-3 (OLS, n=200, p=20 000, equicorrelated ρ=0.5).  f32
+# rounding of β is not what bounds the certificate — an f64 solution
+# rounded to f32 keeps its level — so the f32 path is held to the f64
+# path's level.
+KKT_RTOL = 1e-2
+KKT_LEVELS = (1e-3, KKT_RTOL, 1e-1, 1.0)  # reported per step
 # kernels vs oracle: max |kernel − oracle| ≤ this × max |oracle| (f32
 # accumulation over ≤ 20 480 terms sits near 1e-6; one bf16 pass near 4e-3)
 KERNEL_RTOL = 1e-4
@@ -123,12 +127,9 @@ def kkt_levels(X, y, betas, sigmas, lam, family):
 
 
 def kkt_gate(levels, what):
-    worst, median = float(np.max(levels)), float(np.median(levels))
-    check(worst <= KKT_RTOL_EVERY,
-          f"{what}: a step fails the KKT test at rtol {KKT_RTOL_EVERY} "
+    check(float(np.max(levels)) <= KKT_RTOL,
+          f"{what}: a step fails the KKT test at rtol {KKT_RTOL} "
           f"(levels {levels.tolist()})")
-    check(median <= KKT_RTOL_MEDIAN,
-          f"{what}: median step passes only at rtol {median}")
     hist = {f"{t:g}": int(np.sum(levels == t)) for t in KKT_LEVELS}
     return f"kkt {len(levels)} steps ok (steps per rtol {hist})"
 
@@ -187,29 +188,29 @@ def run_cv(sizes, seed, clock):
               else np.asarray(fp.compact_fallback))
         print(f"  compact fallback steps per fold: {fb.sum(axis=1).tolist()}")
         best = cv.best_index
-        # the fold fits at the selected σ are reported, not gated: the
-        # gate is the full-data refit there
+        # every fold path past its null head: index 0 emits β = 0 at the
+        # full-data σ_max, which a fold's own σ_max may exceed
         trains, _ = cv_fold_indices(y, folds, family=ols)
-        fold_lv = np.concatenate([
-            kkt_levels(X[tr], y[tr], fp.betas[f, best:best + 1],
-                       cv.sigmas[best:best + 1], cv.lam, "ols")
-            for f, tr in enumerate(trains)])
-        print(f"  folds at σ index {best}: kkt levels {fold_lv.tolist()}, "
-              f"solver iters {fp.solver_iters[:, best].tolist()}")
+        fold_lv = [kkt_levels(X[tr], y[tr], fp.betas[f, 1:], cv.sigmas[1:],
+                              cv.lam, "ols")
+                   for f, tr in enumerate(trains)]
+        for f, lv in enumerate(fold_lv):
+            print(f"  fold {f}: solver iters "
+                  f"{int(fp.solver_iters[f].sum())}, kkt level per step "
+                  f"{lv.tolist()}")
+        folds_ok = kkt_gate(np.concatenate(fold_lv), "cv folds")
         refit = slope_path(Problem(X, y, ols),
                            PathSpec(LambdaSpec("bh", q=0.1),
                                     sigmas=cv.sigmas[:best + 1],
                                     early_stop=False), SolverPolicy())
         lv = kkt_levels(X, y, refit.betas[-1:], refit.sigmas[-1:],
                         refit.lam, "ols")
-        info["check"] = (f"best σ index {best}/{L}; refit "
-                         + kkt_gate(lv, "cv refit"))
+        info["check"] = (f"folds {folds_ok}; best σ index {best}/{L}; "
+                         f"refit " + kkt_gate(lv, "cv refit"))
 
 
 def served_requests(sizes, seed):
-    """Seeded OLS requests on the paper's uncorrelated design (ρ = 0): the
-    8-slot masked chunk program runs every slot in lockstep, and at ρ = 0.5
-    its f32 solves hit max_iter — the phase then outlasts the run."""
+    """Seeded OLS requests on the direct fits' equicorrelated design."""
     from repro.data import make_regression
 
     rng = np.random.default_rng(seed)
@@ -217,17 +218,28 @@ def served_requests(sizes, seed):
     for i in range(sizes["requests"]):
         n = int(rng.integers(sizes["n_lo"], sizes["n_hi"] + 1))
         p = int(rng.integers(sizes["p_lo"], sizes["p_hi"] + 1))
-        X, y, _ = make_regression(n, p, sizes["k"], rho=0.0,
+        X, y, _ = make_regression(n, p, sizes["k"], rho=0.5,
                                   seed=seed + 1 + i, design="equi")
         reqs.append((X.astype(np.float32), y.astype(np.float32)))
     return reqs
 
 
-def serve_all(reqs, store_dir, L):
-    from repro.api import LambdaSpec, PathSpec, Problem, SolverPolicy
+def top_of_path(sizes):
+    """The served and x64 phases' path: the first ``L_top`` points of the
+    direct fits' σ grid (the same spacing), where the f32 solves at ρ = 0.5
+    once ran to max_iter."""
+    from repro.api import LambdaSpec, PathSpec
+
+    L, Lt = sizes["L"], sizes["L_top"]
+    # 1e-2: the default grid's σ(L)/σ(1) for n < p (paper §3.1.2)
+    return PathSpec(LambdaSpec("bh", q=0.1), path_length=Lt,
+                    sigma_ratio=1e-2 ** ((Lt - 1) / (L - 1)))
+
+
+def serve_all(reqs, store_dir, spec):
+    from repro.api import Problem, SolverPolicy
     from repro.serve import AsyncPathService, DurableProgramStore, Rejection
 
-    spec = PathSpec(LambdaSpec("bh", q=0.1), path_length=L)
     policy = SolverPolicy(working_set=None)  # continuous batching: masked
     svc = AsyncPathService(store=DurableProgramStore(store_dir))
     try:
@@ -244,13 +256,12 @@ def serve_all(reqs, store_dir, L):
 
 
 def run_served(sizes, seed, store_dir, clock):
-    from repro.api import LambdaSpec, PathSpec, Problem, SolverPolicy, slope_path
+    from repro.api import Problem, SolverPolicy, slope_path
 
     reqs = served_requests(sizes, seed)
-    L = sizes["L_served"]
+    spec = top_of_path(sizes)
     with phase("served", clock) as info:
-        resps, _, stats = serve_all(reqs, store_dir, L)
-        spec = PathSpec(LambdaSpec("bh", q=0.1), path_length=L)
+        resps, _, stats = serve_all(reqs, store_dir, spec)
         levels = []
         for (X, y), r in zip(reqs, resps):
             direct = slope_path(Problem(X[None], y[None]), spec,
@@ -271,9 +282,9 @@ def run_served(sizes, seed, store_dir, clock):
     return reqs, resps
 
 
-def run_restart(reqs, first, store_dir, L, clock):
+def run_restart(reqs, first, store_dir, spec, clock):
     with phase("restart", clock) as info:
-        resps, boot, stats = serve_all(reqs, store_dir, L)
+        resps, boot, stats = serve_all(reqs, store_dir, spec)
         check(stats["builds"] == 0,
               f"restart compiled {stats['builds']} programs (want 0)")
         check(stats["store"]["loaded"] > 0, "nothing loaded from the store")
@@ -394,12 +405,15 @@ def _fallbacks(ops):
 
 # -- driver -------------------------------------------------------------------
 
-# served requests carry 50-step paths: each of the 8 direct references
-# runs the service's 8-slot program alone, so the phase costs ~7 service
-# rounds (489 s at 100 steps on a TPU v5e — too much of the run's limit)
-FULL = dict(n=200, p=20000, k=20, L=100, L_served=50, requests=8, n_lo=150,
+# served and x64 paths are the top L_top points of the 100-point grid
+# (σ down to 0.66·σ_max at 10): each of the 8 served requests' direct
+# references runs the service's 8-slot program alone, so that phase costs
+# about ten 8-slot path runs; f64 is emulated on v5e; and the whole smoke
+# must stay within the chip check's 1 200 s.  10 points still cross one
+# chunk boundary of the async service.
+FULL = dict(n=200, p=20000, k=20, L=100, L_top=10, requests=8, n_lo=150,
             n_hi=256, p_lo=12000, p_hi=20000, kn=256, kp=20480)
-TINY = dict(n=40, p=400, k=5, L=20, L_served=20, requests=4, n_lo=20,
+TINY = dict(n=40, p=400, k=5, L=20, L_top=12, requests=4, n_lo=20,
             n_hi=40, p_lo=100, p_hi=400, kn=64, kp=1024)
 
 
@@ -433,8 +447,12 @@ def main(argv=None) -> int:
 
     run_direct(sizes, args.seed, np.float32, clock)
     run_cv(sizes, args.seed, clock)
-    reqs, first = run_served(sizes, args.seed, store_dir, clock)
-    run_restart(reqs, first, store_dir, sizes["L_served"], clock)
+    try:
+        reqs, first = run_served(sizes, args.seed, store_dir, clock)
+        run_restart(reqs, first, store_dir, top_of_path(sizes), clock)
+    finally:
+        # its 8-slot executables run to tens of MiB: keep the output small
+        shutil.rmtree(store_dir, ignore_errors=True)
     run_kernels(sizes, args.seed, clock)
     jax.config.update("jax_enable_x64", True)
     try:
@@ -449,21 +467,21 @@ def main(argv=None) -> int:
 
 
 def run_direct_f64(sizes, seed, clock):
-    """Phase 2's logistic fit in f64, x64 on (f64 is emulated on v5e)."""
-    from repro.api import LambdaSpec, PathSpec, Problem, SolverPolicy, slope_path
-    from repro.core import logistic
-    from repro.data import make_classification
+    """Phase 2's OLS fit in f64 over the top of its path, x64 on (f64 is
+    emulated on v5e)."""
+    from repro.api import Problem, SolverPolicy, slope_path
+    from repro.core import ols
+    from repro.data import make_regression
 
-    n, p, k, L = sizes["n"], sizes["p"], sizes["k"], sizes["L"]
-    X, y, _ = make_classification(n, p, k, rho=0.5, seed=seed, design="ar")
-    with phase("x64/logistic", clock) as info:
-        res = slope_path(Problem(X, y, logistic),
-                         PathSpec(LambdaSpec("bh", q=0.1), path_length=L),
+    n, p, k = sizes["n"], sizes["p"], sizes["k"]
+    X, y, _ = make_regression(n, p, k, rho=0.5, seed=seed, design="equi")
+    with phase("x64/ols", clock) as info:
+        res = slope_path(Problem(X, y, ols), top_of_path(sizes),
                          SolverPolicy())
         check(res.betas.dtype == np.float64, f"betas {res.betas.dtype}")
-        lv = kkt_levels(X, y, res.betas, res.sigmas, res.lam, "logistic")
+        lv = kkt_levels(X, y, res.betas, res.sigmas, res.lam, "ols")
         info["check"] = (f"n={n} p={p} steps={len(res.steps)} "
-                         + kkt_gate(lv, "x64/logistic"))
+                         + kkt_gate(lv, "x64/ols"))
 
 
 if __name__ == "__main__":

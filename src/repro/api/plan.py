@@ -49,8 +49,8 @@ class ExecutionPlan:
     mode); ``ws_tiers`` the previewed tier widths — ``(W,)`` single-tier or
     ``(W, 2W)`` two-tier (None outside compact mode); ``exec_shape`` the
     padded ``(slots, N, P)`` program shape when ``pad="bucket"`` (slots is
-    None for served plans — the slot count is the serving deployment's
-    batch bucket).
+    ``ShapeBucketPolicy.direct_slots`` for direct plans, None for served
+    plans — the slot count is the serving deployment's batch bucket).
     """
 
     backend: str
@@ -202,13 +202,19 @@ def plan_execution(problem: Problem, path: PathSpec | None = None,
     if pad == "bucket":
         pol = default_policy()
         N, P = pol.shape_bucket(n_fit, p, family.name)
-        slots = None if serve else pol.batch_bucket(B)
+        slots = None if serve else pol.direct_slots(B)
         exec_shape = (slots, N, P)
         n_key, p_key = N, P
         reasons.append(
             f"canonical execution shape rows×cols = {N}×{P} "
             f"(power-of-two buckets, inert zero padding; rows padded for "
             f"OLS only)")
+        if not serve:
+            reasons.append(
+                f"{slots} batch slots for {B} problem(s): the default "
+                f"services' width, so the fit runs the programs a served "
+                f"request runs (bitwise to it); dummy slots cost device "
+                f"work")
 
     # -- backend ------------------------------------------------------------
     if policy.backend == "host":

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..serve.buckets import STEP_CHUNK, BucketRegistry, next_pow2
+from ..serve.buckets import BucketRegistry, next_pow2
 from .kkt import kkt_violations_masked
 from .lambda_seq import path_start_sigma, sigma_grid
 from .losses import Family
@@ -1367,13 +1367,11 @@ def _fit_path_batched(
     Xs_run, ys_run, lam_run, sig_run = Xs, ys, lam, sigmas
     n_run, p_run = n, p
     if pad == "bucket":
-        from ..serve.buckets import SERVE_SLOTS, default_policy, pad_batch
+        from ..serve.buckets import default_policy, pad_batch
 
         policy = default_policy()
         n_run, p_run = policy.shape_bucket(n, p, family.name)
-        # the default service's slot count: a masked bucket fit runs its
-        # very programs (see _chunked_path)
-        slots = policy.batch_bucket(max(B, SERVE_SLOTS))
+        slots = policy.direct_slots(B)
         lam2 = lam if lam.ndim == 2 else np.broadcast_to(lam, (B, p * m))
         pb = pad_batch(
             [(Xs[b], ys[b], lam2[b], sigmas[b]) for b in range(B)],
@@ -1389,7 +1387,8 @@ def _fit_path_batched(
     stats = None
     if working_set is None and pad == "bucket":
         res = _chunked_path(Xs_run, ys_run, lam_run, sig_run, family,
-                            p_valid, step_chunk=STEP_CHUNK, **engine_kw)
+                            p_valid, step_chunk=policy.step_chunk,
+                            **engine_kw)
     elif working_set is None:
         res = batched_path_engine(
             jnp.asarray(Xs_run), jnp.asarray(ys_run), jnp.asarray(lam_run),

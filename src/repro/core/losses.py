@@ -82,20 +82,16 @@ class Family:
         return matmul(X.T, self.weighted_residual(matmul(X, beta), y,
                                                       weights))
 
-    def loss_and_gradient(self, X, y, beta, weights=None):
-        """(f(β), ∇f(β)) sharing ONE linear predictor z = Xβ.
-
-        Separate ``loss``/``gradient`` calls each build their own Xβ and
-        only merge if XLA's CSE happens to fire; this fuses the pair by
-        construction, so a FISTA step streams X for z once plus once for
-        the Xᵀr matvec.  The Pallas analogue is
-        :func:`repro.kernels.slope_loss_residual`.
-        """
-        z = matmul(X, beta)
+    def value_residual(self, z, y, weights=None):
+        """(f, w ⊙ r) at the linear predictor z = Xβ: the loss and the
+        weighted residual whose Xᵀ-product is the gradient.  A FISTA step
+        feeds both from ONE z, so it streams X once for z and once for
+        the Xᵀr matvec; the Pallas analogue is
+        :func:`repro.kernels.slope_loss_residual`."""
         if weights is None:
-            return self.value(z, y), matmul(X.T, self.residual(z, y))
+            return self.value(z, y), self.residual(z, y)
         return (self.weighted_value(z, y, weights),
-                matmul(X.T, self.weighted_residual(z, y, weights)))
+                self.weighted_residual(z, y, weights))
 
     def lipschitz(self, X) -> jax.Array:
         """Upper bound on the gradient Lipschitz constant: c·‖X‖₂²."""

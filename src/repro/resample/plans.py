@@ -93,7 +93,7 @@ class ResamplePlan:
         """Per-member row weights ``(B, n)`` — counts, 0/1 masks or ones.
 
         This is the array the replicate engines thread through
-        ``Family.loss_and_gradient``; it is the *only* per-member state of
+        ``Family.value_residual``; it is the *only* per-member state of
         O(n) size the fused execution needs.
         """
         keys = self.keys()

@@ -47,8 +47,6 @@ __all__ = [
     "default_policy",
     "PaddedBatch",
     "pad_batch",
-    "SERVE_SLOTS",
-    "STEP_CHUNK",
 ]
 
 _MISSING = object()
@@ -187,14 +185,6 @@ class BucketRegistry:
                 f"capacity={self.capacity})")
 
 
-# the default services' batch slots and continuous-batching chunk length.
-# A direct pad="bucket" fit runs the default async service's own programs
-# (these slots, these chunks): on a TPU, programs of another batch width or
-# loop structure round differently, so only the same programs are bitwise.
-SERVE_SLOTS = 8
-STEP_CHUNK = 8
-
-
 @dataclasses.dataclass(frozen=True)
 class ShapeBucketPolicy:
     """Power-of-two padding policy for incoming ``(n, p)`` problems.
@@ -210,11 +200,20 @@ class ShapeBucketPolicy:
 
     Floors bound the number of distinct compiled shapes a mixed stream can
     produce; raise them if a deployment sees too many tiny odd shapes.
+
+    ``serve_slots`` and ``step_chunk`` are the services' default batch
+    width and the async service's continuous-batching chunk length.  A
+    direct ``pad="bucket"`` fit runs at the same width (:meth:`direct_slots`)
+    and, when masked, in the same chunks, so it runs the very programs the
+    default service runs: on a TPU, programs of another batch width or loop
+    structure round differently, and only the same programs are bitwise.
     """
 
     min_rows: int = 16
     min_cols: int = 32
     min_batch: int = 2
+    serve_slots: int = 8
+    step_chunk: int = 8
 
     def shape_bucket(self, n: int, p: int, family_name: str = "ols"):
         """Execution shape ``(N, P)`` for a native ``(n, p)`` problem."""
@@ -225,6 +224,11 @@ class ShapeBucketPolicy:
     def batch_bucket(self, b: int) -> int:
         """Execution batch width for ``b`` live requests."""
         return max(self.min_batch, next_pow2(b))
+
+    def direct_slots(self, b: int) -> int:
+        """Batch width of a direct ``pad="bucket"`` fit of ``b`` problems:
+        the default services' width, unused slots holding inert dummies."""
+        return self.batch_bucket(max(b, self.serve_slots))
 
 
 _DEFAULT_POLICY = ShapeBucketPolicy()

@@ -75,7 +75,7 @@ from ..core.losses import Family, ols
 from ..core.path import _stop_triggered
 from ..core.solver import DEFAULT_WS_TIERS
 from .batcher import Pending, QueueFull, Rejection
-from .buckets import SERVE_SLOTS, STEP_CHUNK, pad_batch
+from .buckets import pad_batch
 from .cache import ProgramSpec
 from .durable import (
     InflightSlot,
@@ -132,8 +132,8 @@ class AsyncPathService(PathService):
     testing admission without execution); :meth:`start` launches it.
     """
 
-    def __init__(self, *, max_batch: int = SERVE_SLOTS,
-                 max_delay: float = 0.02, step_chunk: int = STEP_CHUNK,
+    def __init__(self, *, max_batch: int | None = None,
+                 max_delay: float = 0.02, step_chunk: int | None = None,
                  max_queue: int | None = 64,
                  retry_limit: int = 2, retry_backoff: float = 0.02,
                  retry_jitter: float = 0.25,
@@ -154,6 +154,8 @@ class AsyncPathService(PathService):
                          shed_threshold=shed_threshold,
                          shed_priority=shed_priority,
                          shed_window=shed_window)
+        if step_chunk is None:
+            step_chunk = self.policy.step_chunk
         if step_chunk < 1:
             raise ValueError(f"step_chunk must be ≥ 1, got {step_chunk}")
         if retry_limit < 0:

@@ -133,7 +133,7 @@ class DurableProgramStore:
         os.makedirs(self.path, exist_ok=True)
         self._lock = threading.Lock()
         self.counters = {"saved": 0, "loaded": 0, "discarded": 0,
-                         "replayed": 0, "recompiled": 0, "unserializable": 0}
+                         "replayed": 0, "unserializable": 0}
 
     # -- keying -------------------------------------------------------------
 
@@ -156,24 +156,14 @@ class DurableProgramStore:
         the executable."""
         from jax.experimental import serialize_executable as se
 
-        from .cache import _build
-
         self._append_manifest(spec)
         try:
             payload, in_tree, out_tree = se.serialize(prog._compiled)
         except jax.errors.JaxRuntimeError:
-            # XLA:CPU cannot serialize an executable once it has run a
-            # sort, and JAX hands every compile of one program in a process
-            # the same executable: compile this one afresh, unrun
-            jax.clear_caches()
+            # e.g. XLA:CPU refuses an executable that has already run a sort
             with self._lock:
-                self.counters["recompiled"] += 1
-            try:
-                payload, in_tree, out_tree = se.serialize(_build(spec)[0])
-            except jax.errors.JaxRuntimeError:
-                with self._lock:
-                    self.counters["unserializable"] += 1
-                return False
+                self.counters["unserializable"] += 1
+            return False
         entry = {
             "version": _ENTRY_VERSION,
             "token": _spec_token(spec),

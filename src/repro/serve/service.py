@@ -92,7 +92,7 @@ from .batcher import (
     Rejection,
     RejectionError,
 )
-from .buckets import SERVE_SLOTS, ShapeBucketPolicy, default_policy, pad_batch
+from .buckets import ShapeBucketPolicy, default_policy, pad_batch
 from .cache import ProgramCache, ProgramSpec
 from .durable import (
     CircuitBreaker,
@@ -292,7 +292,8 @@ class PathService:
     inert dummy problems.
     """
 
-    def __init__(self, *, max_batch: int = SERVE_SLOTS, max_delay: float = 0.02,
+    def __init__(self, *, max_batch: int | None = None,
+                 max_delay: float = 0.02,
                  max_queue: int | None = None,
                  policy: ShapeBucketPolicy | None = None,
                  cache: ProgramCache | None = None,
@@ -338,6 +339,8 @@ class PathService:
         self._governor = LoadShedGovernor(threshold=shed_threshold,
                                           priority_cutoff=shed_priority,
                                           min_window=shed_window)
+        if max_batch is None:
+            max_batch = self.policy.serve_slots
         self.slots = self.policy.batch_bucket(max_batch)
         self._batcher = MicroBatcher(max_batch=max_batch, max_delay=max_delay,
                                      max_queue=max_queue)

@@ -263,6 +263,18 @@ def test_planner_agreement_masked_bit_identical():
     np.testing.assert_array_equal(auto.n_screened, legacy.n_screened)
 
 
+@pytest.mark.parametrize("B,backend", [(1, "masked"), (3, "masked"),
+                                       (1, "compact"), (9, "masked")])
+def test_planner_exec_shape_is_what_runs(B, backend):
+    """The plan's padded program shape is the one the engine executes —
+    batch slots included (a direct bucket fit runs the services' width)."""
+    Xs, ys, lam = _batch(B, 20, 40)
+    res = slope_path(Problem(Xs, ys), PathSpec(lam=lam, path_length=4),
+                     SolverPolicy(backend=backend, pad="bucket", **POL))
+    assert res.plan.exec_shape == res.pad_shape
+    assert res.pad_shape[0] == max(8, 1 << (B - 1).bit_length())
+
+
 def test_planner_agreement_host_bit_identical():
     X, y, lam = _problem(30, 40)
     auto = slope_path(Problem(X, y),

@@ -25,7 +25,7 @@ from repro.core import (
 )
 from repro.core.engine import _WS_BUCKETS, cv_fold_indices
 from repro.data import make_classification, make_regression
-from repro.serve.buckets import SERVE_SLOTS
+from repro.serve.buckets import default_policy
 from repro.serve import (
     BucketRegistry,
     LambdaCanonicalizer,
@@ -390,7 +390,7 @@ def test_served_logistic_exact_rows(shared_cache):
     direct = fit_path_batched(X[None], y[None], lam, logistic,
                               pad="bucket", **KW)
     # rows exact, columns padded, the default service's slots
-    assert direct.pad_shape == (SERVE_SLOTS, 20, 32)
+    assert direct.pad_shape == (default_policy().serve_slots, 20, 32)
     np.testing.assert_array_equal(resp.betas, direct.betas[0])
     native = fit_path_batched(X[None], y[None], lam, logistic, **KW)
     np.testing.assert_allclose(resp.betas, native.betas[0], atol=5e-3)
@@ -404,7 +404,7 @@ def test_padded_semantics_match_native_engine():
     X, y, lam = _problem(20, 24, seed=6)
     native = fit_path_batched(X[None], y[None], lam, ols, **KW)
     padded = fit_path_batched(X[None], y[None], lam, ols, pad="bucket", **KW)
-    assert padded.pad_shape == (SERVE_SLOTS, 32, 32)
+    assert padded.pad_shape == (default_policy().serve_slots, 32, 32)
     assert native.pad_shape is None
     np.testing.assert_array_equal(native.n_screened, padded.n_screened)
     np.testing.assert_array_equal(native.n_violations, padded.n_violations)

@@ -87,6 +87,17 @@ def test_prox_optimality_certificate(case):
     assert in_subdifferential(v - x, x, lam, atol=1e-8)
 
 
+def test_prox_certificate_accepts_rounding_level_gradient():
+    """λ ≡ 0 makes the prox the identity, but pooling the tie
+    [0.4, 0.4, 0.4] rounds its mean up: v − x = −5.6e-17 has the wrong
+    sign on an active coefficient.  It lies within tol of the admissible
+    0, so the certificate accepts it."""
+    v, lam = np.array([0.4, 0.4, 0.4]), np.zeros(3)
+    vp, lamp = _pad_prox_case(v, lam)
+    x = np.asarray(prox_sorted_l1(jnp.asarray(vp), jnp.asarray(lamp)))[:3]
+    assert in_subdifferential(v - x, x, lam, atol=1e-8)
+
+
 def test_prox_is_projection_when_lam_zero(rng):
     v = rng.normal(size=50)
     lam = np.zeros(50)
