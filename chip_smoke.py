@@ -12,19 +12,20 @@ compile and run, and its check (any failure raises; nothing is caught):
               BH path): OLS on an equicorrelated design, logistic on an AR
               design; every returned step passes the NumPy f64 KKT test.
 3. cv       — 10-fold OLS CV through the planner (compact engine) on the
-              same design; every fold path past its null head (index 0,
+              same design, over the top 20 points of the 100-point σ
+              grid; every fold path past its null head (index 0,
               β = 0 by construction) passes the KKT test on its training
               rows, and so does the full-data refit at the selected σ.
 4. served   — ``AsyncPathService`` answers 8 OLS requests of seeded sizes
               (n ∈ [150, 256], p ∈ [12 000, 20 000], equicorrelated
-              ρ = 0.5, the top 10 points of the 100-point σ grid, run in
-              two chunks); each response equals a direct ``pad="bucket"``
+              ρ = 0.5, the top 3 points of the 100-point σ grid); each
+              response equals a direct ``pad="bucket"``
               fit bitwise and passes the KKT test.
 5. restart  — a second service boots from the durable program store phase
               4 filled, compiles nothing, and serves the same betas bitwise.
 6. kernels  — each Pallas kernel once at a real width against its
               ``repro.kernels.ref`` oracle evaluated on the host CPU.
-7. x64      — phase 2's OLS fit over the same top 10 grid points, with
+7. x64      — phase 2's OLS fit over the top 4 points of its grid, with
               x64 on, in f64.
 
 Phases 2–6 run in f32 with x64 off, so no f64 op hides in them.  The last
@@ -172,14 +173,14 @@ def run_cv(sizes, seed, clock):
     from repro.core.engine import cv_fold_indices
     from repro.data import make_regression
 
-    n, p, k, L = sizes["n"], sizes["p"], sizes["k"], sizes["L"]
+    n, p, k = sizes["n"], sizes["p"], sizes["k"]
     X, y, _ = make_regression(n, p, k, rho=0.5, seed=seed, design="equi")
     X, y = X.astype(np.float32), y.astype(np.float32)
     folds = 10
     with phase("cv", clock) as info:
         cv = slope_path(Problem(X, y, ols),
-                        PathSpec(LambdaSpec("bh", q=0.1), path_length=L,
-                                 cv_folds=folds), SolverPolicy())
+                        top_of_path(sizes, sizes["L_cv"], cv_folds=folds),
+                        SolverPolicy())
         fp = cv.fold_paths
         print(f"  plan: {cv.plan.summary()}")
         check(cv.plan.mode == "compact",
@@ -205,7 +206,8 @@ def run_cv(sizes, seed, clock):
                                     early_stop=False), SolverPolicy())
         lv = kkt_levels(X, y, refit.betas[-1:], refit.sigmas[-1:],
                         refit.lam, "ols")
-        info["check"] = (f"folds {folds_ok}; best σ index {best}/{L}; "
+        info["check"] = (f"folds {folds_ok}; best σ index {best}/"
+                         f"{len(cv.sigmas)}; "
                          f"refit " + kkt_gate(lv, "cv refit"))
 
 
@@ -224,16 +226,14 @@ def served_requests(sizes, seed):
     return reqs
 
 
-def top_of_path(sizes):
-    """The served and x64 phases' path: the first ``L_top`` points of the
-    direct fits' σ grid (the same spacing), where the f32 solves at ρ = 0.5
-    once ran to max_iter."""
+def top_of_path(sizes, Lt, **spec):
+    """The first ``Lt`` points of the direct fits' σ grid (the same
+    spacing): the path of the cv, served and x64 phases."""
     from repro.api import LambdaSpec, PathSpec
 
-    L, Lt = sizes["L"], sizes["L_top"]
     # 1e-2: the default grid's σ(L)/σ(1) for n < p (paper §3.1.2)
     return PathSpec(LambdaSpec("bh", q=0.1), path_length=Lt,
-                    sigma_ratio=1e-2 ** ((Lt - 1) / (L - 1)))
+                    sigma_ratio=1e-2 ** ((Lt - 1) / (sizes["L"] - 1)), **spec)
 
 
 def serve_all(reqs, store_dir, spec):
@@ -259,7 +259,7 @@ def run_served(sizes, seed, store_dir, clock):
     from repro.api import Problem, SolverPolicy, slope_path
 
     reqs = served_requests(sizes, seed)
-    spec = top_of_path(sizes)
+    spec = top_of_path(sizes, sizes["L_served"])
     with phase("served", clock) as info:
         resps, _, stats = serve_all(reqs, store_dir, spec)
         levels = []
@@ -405,16 +405,20 @@ def _fallbacks(ops):
 
 # -- driver -------------------------------------------------------------------
 
-# served and x64 paths are the top L_top points of the 100-point grid
-# (σ down to 0.66·σ_max at 10): each of the 8 served requests' direct
-# references runs the service's 8-slot program alone, so that phase costs
-# about ten 8-slot path runs; f64 is emulated on v5e; and the whole smoke
-# must stay within the chip check's 1 200 s.  10 points still cross one
-# chunk boundary of the async service.
-FULL = dict(n=200, p=20000, k=20, L=100, L_top=10, requests=8, n_lo=150,
-            n_hi=256, p_lo=12000, p_hi=20000, kn=256, kp=20480)
-TINY = dict(n=40, p=400, k=5, L=20, L_top=12, requests=4, n_lo=20,
-            n_hi=40, p_lo=100, p_hi=400, kn=64, kp=1024)
+# The direct fits run the whole 100-point grid; the cv, served and x64
+# phases its top L_cv, L_served and L_x64 points, so that the smoke stays
+# well within the chip check's 1 200 s.  On one v5e the whole-grid 10-fold
+# CV took 475 s (78 of 100 steps per fold in the masked O(n·p) fallback);
+# a masked solve at the served bucket (8, 256, 32 768) pays ~20 ms per
+# sorted-ℓ1 prox, and each of the 8 requests' direct references runs that
+# 8-slot program alone; f64 is emulated.  The tiny sizes keep paths that
+# cross a chunk boundary of the async service.
+FULL = dict(n=200, p=20000, k=20, L=100, L_cv=20, L_served=3, L_x64=4,
+            requests=8, n_lo=150, n_hi=256, p_lo=12000, p_hi=20000, kn=256,
+            kp=20480)
+TINY = dict(n=40, p=400, k=5, L=20, L_cv=20, L_served=12, L_x64=12,
+            requests=4, n_lo=20, n_hi=40, p_lo=100, p_hi=400, kn=64,
+            kp=1024)
 
 
 def main(argv=None) -> int:
@@ -449,7 +453,8 @@ def main(argv=None) -> int:
     run_cv(sizes, args.seed, clock)
     try:
         reqs, first = run_served(sizes, args.seed, store_dir, clock)
-        run_restart(reqs, first, store_dir, top_of_path(sizes), clock)
+        run_restart(reqs, first, store_dir,
+                    top_of_path(sizes, sizes["L_served"]), clock)
     finally:
         # its 8-slot executables run to tens of MiB: keep the output small
         shutil.rmtree(store_dir, ignore_errors=True)
@@ -476,8 +481,8 @@ def run_direct_f64(sizes, seed, clock):
     n, p, k = sizes["n"], sizes["p"], sizes["k"]
     X, y, _ = make_regression(n, p, k, rho=0.5, seed=seed, design="equi")
     with phase("x64/ols", clock) as info:
-        res = slope_path(Problem(X, y, ols), top_of_path(sizes),
-                         SolverPolicy())
+        res = slope_path(Problem(X, y, ols),
+                         top_of_path(sizes, sizes["L_x64"]), SolverPolicy())
         check(res.betas.dtype == np.float64, f"betas {res.betas.dtype}")
         lv = kkt_levels(X, y, res.betas, res.sigmas, res.lam, "ols")
         info["check"] = (f"n={n} p={p} steps={len(res.steps)} "

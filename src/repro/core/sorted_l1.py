@@ -29,6 +29,7 @@ __all__ = [
     "isotonic_decreasing",
     "isotonic_decreasing_minimax",
     "isotonic_decreasing_parallel",
+    "isotonic_decreasing_pool",
     "clusters",
 ]
 
@@ -104,6 +105,8 @@ def isotonic_decreasing_parallel(y: jax.Array) -> jax.Array:
     p = y.shape[0]
     dtype = y.dtype
     idx = jnp.arange(p)
+    if dtype == jnp.float64:
+        S = jnp.concatenate([jnp.zeros((1,), dtype), jnp.cumsum(y)])
 
     def block_means(start):
         # scatter-free segment means: a block is [begin, end) where begin is
@@ -115,7 +118,11 @@ def isotonic_decreasing_parallel(y: jax.Array) -> jax.Array:
         end = jnp.concatenate([nxt[1:], jnp.full((1,), p, idx.dtype)])
         # block sums from a prefix sum that restarts at every block start:
         # differences of ONE global cumsum lose everything below eps·|Σy|,
-        # which in f32 turns zero-clipped blocks into spurious nonzeros
+        # which in f32 turns zero-clipped blocks into spurious nonzeros.
+        # In f64 that loss sits below every tolerance of the solver, and
+        # f64 keeps the plain global form.
+        if dtype == jnp.float64:
+            return (S[end] - S[begin]) / (end - begin).astype(dtype)
         _, seg = lax.associative_scan(_segmented_add, (start, y))
         return seg[end - 1] / (end - begin).astype(dtype)
 
@@ -164,6 +171,15 @@ def isotonic_decreasing_minimax(y: jax.Array) -> jax.Array:
     return jnp.diagonal(lax.cummin(R, axis=0))
 
 
+def isotonic_decreasing_pool(y: jax.Array) -> jax.Array:
+    """:func:`isotonic_decreasing` clipped at 0, by the Pallas kernel
+    (:func:`repro.kernels.ops.prox_pool`): the same stack PAVA, run on the
+    TPU core's scalar unit out of its scalar memory.  f32 only."""
+    from ..kernels.ops import prox_pool  # the kernels import this module
+
+    return prox_pool(y)
+
+
 @functools.partial(jax.jit, static_argnames=("method",))
 def prox_sorted_l1_with_norm(v: jax.Array, lam: jax.Array, *,
                              method: str = "stack"):
@@ -184,6 +200,7 @@ def prox_sorted_l1_with_norm(v: jax.Array, lam: jax.Array, *,
         "stack": isotonic_decreasing,
         "parallel": isotonic_decreasing_parallel,
         "minimax": isotonic_decreasing_minimax,
+        "pool": isotonic_decreasing_pool,
     }[method](w)
     x_sorted = jnp.maximum(iso, 0)
     x = jnp.zeros_like(v).at[order].set(x_sorted)
@@ -198,8 +215,8 @@ def prox_sorted_l1(v: jax.Array, lam: jax.Array, *, method: str = "stack") -> ja
     ``method='stack'`` is the lax.while_loop PAVA here; ``method='parallel'``
     the sweep-merging form (:func:`isotonic_decreasing_parallel`) the
     batched device engine uses; ``method='minimax'`` the O(p²)-work
-    depth-parallel form; the Pallas kernel path lives in
-    :mod:`repro.kernels.ops` and is validated against this.
+    depth-parallel form; ``method='pool'`` the stack PAVA as a Pallas
+    kernel (:func:`isotonic_decreasing_pool`, f32).
     """
     return prox_sorted_l1_with_norm(v, lam, method=method)[0]
 

@@ -183,3 +183,27 @@ def test_f32_path_converges_on_equicorrelated_design():
         grad = X64.T @ (X64 @ b - y64)
         assert kkt_optimal(grad, b, float(s) * lam.astype(np.float64),
                            atol=0.0, rtol=1e-2)
+
+
+def test_host_path_pools_in_the_kernel_on_a_tpu(monkeypatch):
+    """On a TPU the host driver's f32 sub-solves take the Pallas pooling
+    kernel (run here in the interpreter); the path is the one the XLA stack
+    prox gives, bit for bit.  f64 keeps the XLA stack."""
+    import jax
+
+    from repro.core import path as path_mod
+    from repro.kernels import ops
+
+    X, y, _ = make_regression(30, 120, 5, rho=0.5, seed=3, design="equi")
+    X, y = X.astype(np.float32), y.astype(np.float32)
+    lam = np.asarray(bh_sequence(120, q=0.1), np.float32)
+    kw = dict(screening="strong", path_length=12, max_iter=2000)
+    want = fit_path(X, y, lam, ols, **kw)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_interpret", lambda: True)  # no chip here
+    assert path_mod._host_prox_method(np.float32) == "pool"
+    assert path_mod._host_prox_method(np.float64) == "stack"
+    got = fit_path(X, y, lam, ols, **kw)
+    np.testing.assert_array_equal(got.betas, want.betas)
+    assert [s.solver_iters for s in got.steps] == [
+        s.solver_iters for s in want.steps]

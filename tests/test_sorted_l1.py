@@ -163,6 +163,23 @@ def test_isotonic_parallel_f32_matches_stack_at_path_width():
     np.testing.assert_array_equal(got > 0, want > 0)
 
 
+def test_prox_pool_method_matches_stack_f32():
+    """The Pallas pooling kernel runs the stack PAVA's arithmetic: in f32
+    the ``pool`` prox returns the ``stack`` prox's values and norm."""
+    from repro.core.sorted_l1 import prox_sorted_l1_with_norm
+
+    rng = np.random.default_rng(5)
+    p = 512
+    lam = jnp.asarray(bh_sequence(p, q=0.1), jnp.float32)
+    for scale in (0.5, 3.0):
+        v = jnp.asarray(rng.normal(size=p) * scale, jnp.float32)
+        x_s, j_s = prox_sorted_l1_with_norm(v, lam, method="stack")
+        x_p, j_p = prox_sorted_l1_with_norm(v, lam, method="pool")
+        assert x_p.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(x_p), np.asarray(x_s))
+        np.testing.assert_array_equal(np.asarray(j_p), np.asarray(j_s))
+
+
 def test_norm_properties(rng):
     p = 64
     lam = np.sort(np.abs(rng.normal(size=p)))[::-1]
