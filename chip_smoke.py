@@ -409,9 +409,9 @@ def _fallbacks(ops):
 # phases its top L_cv, L_served and L_x64 points, so that the smoke stays
 # well within the chip check's 1 200 s.  On one v5e the whole-grid 10-fold
 # CV took 475 s (78 of 100 steps per fold in the masked O(n·p) fallback);
-# a masked solve at the served bucket (8, 256, 32 768) pays ~20 ms per
-# sorted-ℓ1 prox, and each of the 8 requests' direct references runs that
-# 8-slot program alone; f64 is emulated.  The tiny sizes keep paths that
+# each of the 8 served requests' direct references runs the 8-slot
+# (256, 16 384 or 32 768) program alone, and one such fit over the top 3
+# points took 6 s to run; f64 is emulated.  The tiny sizes keep paths that
 # cross a chunk boundary of the async service.
 FULL = dict(n=200, p=20000, k=20, L=100, L_cv=20, L_served=3, L_x64=4,
             requests=8, n_lo=150, n_hi=256, p_lo=12000, p_hi=20000, kn=256,

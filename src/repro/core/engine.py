@@ -47,6 +47,7 @@ from .kkt import kkt_violations_masked
 from .lambda_seq import path_start_sigma, sigma_grid
 from .losses import Family
 from .screening import screen_masked
+from .sorted_l1 import pool_prox_applies
 from .solver import (
     DEFAULT_KKT_TOL,
     DEFAULT_MAX_REFITS,
@@ -251,6 +252,17 @@ def _new_violations(viol_flat, strong_p, prev_active, *, p, m, screening):
     return miss.sum().astype(jnp.int32)
 
 
+def _batched_prox_method(dtype) -> str:
+    """The batched engines' sorted-ℓ1 prox.  The stack PAVA as an XLA loop
+    is p·m sequential trips, and under vmap every member pays the slowest
+    member's pooling in lockstep; the sweep-merging form is a handful of
+    dense ops per sweep and batches well — until one cluster spans most
+    coordinates (SLOPE near σ_max on an equicorrelated design), where it
+    needs O(p) sweeps.  On a TPU, in f32, the Pallas pool kernel runs the
+    stack PAVA's O(p) on the scalar unit, one member after another."""
+    return "pool" if pool_prox_applies(dtype) else "parallel"
+
+
 def _step_builder(X, y, lam, family: Family, screening, max_iter, tol,
                   kkt_tol, max_refits, rw=None, shared_x=False):
     """Build the per-σ-point path step for ONE problem.
@@ -306,17 +318,16 @@ def _step_builder(X, y, lam, family: Family, screening, max_iter, tol,
     def lift(b):  # family shape -> (p, m)
         return b[:, None] if m == 1 else b
 
+    prox_method = _batched_prox_method(dtype)
+
     def solve(Xs, ys, E, lam_next, beta, L, rws=None):
-        # The stack PAVA prox is a p·m-length sequential loop — under vmap
-        # every batch member pays the slowest member's pooling in lockstep.
-        # The sweep-merging prox is a handful of dense ops per sweep, so it
-        # batches with near-perfect efficiency.  L is the curvature estimate
-        # carried from the previous solve — device-resident state the host
-        # driver cannot keep, which skips the backtracking ramp-up.
+        # L is the curvature estimate carried from the previous solve —
+        # device-resident state the host driver cannot keep, which skips
+        # the backtracking ramp-up.
         masked = fista_shared_masked if shared_x else fista_masked
         res = masked(Xs, ys, lam_next, fam_shape(beta), E, family,
                      max_iter=max_iter, tol=tol,
-                     prox_method="parallel", L0=L, weights=rws)
+                     prox_method=prox_method, L0=L, weights=rws)
         beta_new = lift(res.beta)
         grad = lift(family.gradient(Xs, ys, fam_shape(beta_new),
                                     weights=rws))
@@ -726,7 +737,8 @@ def _compact_engine(X, y, lam, sigmas, family: Family, screening, max_iter,
     dev_bound = _DIVERGENCE_FACTOR * (jnp.abs(null_dev) + 1.0)  # (B,)
     lam_bad = ~jnp.isfinite(lam).all(axis=1)                    # (B,)
 
-    solver_kw = dict(max_iter=max_iter, tol=tol, prox_method="parallel")
+    solver_kw = dict(max_iter=max_iter, tol=tol,
+                     prox_method=_batched_prox_method(dtype))
 
     def solve_masked_one(Xi, yi, wi, lam_next, beta, E, L):
         masked = fista_shared_masked if shared_x else fista_masked

@@ -42,13 +42,13 @@ from typing import Literal
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from .engine import EnginePath, null_gradient, null_sigma_grid, path_engine
 from .kkt import kkt_violations
 from .losses import Family
 from .screening import strong_rule
+from .sorted_l1 import pool_prox_applies
 from .solver import (
     DEFAULT_KKT_TOL,
     DEFAULT_MAX_REFITS,
@@ -106,16 +106,6 @@ def _bucket(width: int, p: int) -> int:
     while b < width:
         b *= 4
     return min(b, p)
-
-
-def _host_prox_method(dtype) -> str:
-    """The host driver's sorted-ℓ1 prox.  On a TPU, f32 sub-solves pool in
-    the Pallas kernel: the XLA stack PAVA runs one loop trip per element
-    there, each a round of small device ops.  Elsewhere, and in f64 (the
-    kernel is f32), the XLA stack."""
-    if jax.default_backend() == "tpu" and np.dtype(dtype) == np.float32:
-        return "pool"
-    return "stack"
 
 
 def _stop_triggered(beta: np.ndarray, dev: float, prev_dev: float,
@@ -305,7 +295,10 @@ def _fit_path_host(
         # family code works with (p,) for scalar families, (p, m) otherwise
         return b[:, 0] if m == 1 else b
 
-    prox_method = _host_prox_method(X.dtype)
+    # on a TPU the XLA stack PAVA runs one loop trip of small device ops
+    # per element; the pool kernel runs the same arithmetic on the scalar
+    # unit
+    prox_method = "pool" if pool_prox_applies(X.dtype) else "stack"
     beta = np.zeros((p, m), dtype=X.dtype)
     grad_full = null_gradient(X, y, family)
     null_dev = float(family.loss(jnp.asarray(X), jnp.asarray(y),

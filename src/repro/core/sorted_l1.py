@@ -30,6 +30,7 @@ __all__ = [
     "isotonic_decreasing_minimax",
     "isotonic_decreasing_parallel",
     "isotonic_decreasing_pool",
+    "pool_prox_applies",
     "clusters",
 ]
 
@@ -171,13 +172,36 @@ def isotonic_decreasing_minimax(y: jax.Array) -> jax.Array:
     return jnp.diagonal(lax.cummin(R, axis=0))
 
 
+@jax.custom_batching.custom_vmap
 def isotonic_decreasing_pool(y: jax.Array) -> jax.Array:
     """:func:`isotonic_decreasing` clipped at 0, by the Pallas kernel
     (:func:`repro.kernels.ops.prox_pool`): the same stack PAVA, run on the
-    TPU core's scalar unit out of its scalar memory.  f32 only."""
+    TPU core's scalar unit out of its scalar memory.  f32 only.  Under
+    ``vmap`` the rows go through the kernel one after another."""
     from ..kernels.ops import prox_pool  # the kernels import this module
 
     return prox_pool(y)
+
+
+@isotonic_decreasing_pool.def_vmap
+def _pool_rows(axis_size, in_batched, y):
+    # the kernel holds one whole row in scalar memory, and Mosaic takes no
+    # batched block there; a row at a time also keeps the stack PAVA's
+    # O(p) cost where the sweep-merging form needs O(p) sweeps (one
+    # cluster spanning most coordinates, as SLOPE gives near σ_max on an
+    # equicorrelated design)
+    (batched,) = in_batched
+    if not batched:
+        return isotonic_decreasing_pool(y), False
+    return lax.map(isotonic_decreasing_pool, y), True
+
+
+def pool_prox_applies(dtype) -> bool:
+    """Whether solves of this dtype take the ``pool`` prox: on a TPU, in
+    f32.  Elsewhere the kernel would run in the Pallas interpreter, and it
+    computes in f32 only."""
+    return (jax.default_backend() == "tpu"
+            and jnp.dtype(dtype) == jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("method",))

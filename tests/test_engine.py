@@ -382,3 +382,47 @@ def test_screen_masked_equals_oracle_on_unmasked_prefix(case):
     if k:
         kept_vals = np.sort(c[keep])[::-1]
         np.testing.assert_array_equal(kept_vals, sub[:k])
+
+
+@pytest.mark.parametrize("working_set", [None, 16])
+def test_engines_pool_in_the_kernel_on_a_tpu(monkeypatch, working_set):
+    """On a TPU the batched engines' f32 solves take the Pallas pooling
+    kernel, one member at a time (run here in the interpreter), in the
+    masked and in the compact engine; every step of the path is
+    KKT-certified at the level of the sweep-merging prox's path."""
+    import jax
+
+    from repro.core import kkt_optimal
+    from repro.kernels import ops
+
+    B, n, p = 2, 20, 40
+    Xs, ys = _batch_problems(B, n, p, rho=0.5)
+    Xs, ys = Xs.astype(np.float32), ys.astype(np.float32)
+    lam = np.asarray(bh_sequence(p, q=0.1), np.float32)
+    kw = dict(path_length=6, solver_tol=1e-8, max_iter=5000,
+              working_set=working_set)
+    traced = []
+    pool = ops.prox_pool
+
+    def counted(w, **k):
+        traced.append(w.shape)
+        return pool(w, **k)
+
+    jax.clear_caches()  # the engines pick their prox while they trace
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "_interpret", lambda: True)  # no chip here
+    monkeypatch.setattr(ops, "prox_pool", counted)
+    try:
+        got = fit_path_batched(Xs, ys, lam, ols, **kw)
+    finally:
+        jax.clear_caches()
+    assert traced and all(s == (p,) for s in traced if working_set is None)
+    assert got.betas.dtype == np.float32
+    assert np.asarray(got.solver_iters).max() < 5000
+    for b in range(B):
+        X64, y64 = Xs[b].astype(np.float64), ys[b].astype(np.float64)
+        for beta, s in zip(got.betas[b], got.sigmas[b]):
+            beta = beta.astype(np.float64).ravel()
+            grad = X64.T @ (X64 @ beta - y64)
+            assert kkt_optimal(grad, beta, float(s) * lam, atol=0.0,
+                               rtol=1e-2)

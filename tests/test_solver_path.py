@@ -191,7 +191,7 @@ def test_host_path_pools_in_the_kernel_on_a_tpu(monkeypatch):
     prox gives, bit for bit.  f64 keeps the XLA stack."""
     import jax
 
-    from repro.core import path as path_mod
+    from repro.core.sorted_l1 import pool_prox_applies
     from repro.kernels import ops
 
     X, y, _ = make_regression(30, 120, 5, rho=0.5, seed=3, design="equi")
@@ -201,8 +201,8 @@ def test_host_path_pools_in_the_kernel_on_a_tpu(monkeypatch):
     want = fit_path(X, y, lam, ols, **kw)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(ops, "_interpret", lambda: True)  # no chip here
-    assert path_mod._host_prox_method(np.float32) == "pool"
-    assert path_mod._host_prox_method(np.float64) == "stack"
+    assert pool_prox_applies(np.float32)
+    assert not pool_prox_applies(np.float64)
     got = fit_path(X, y, lam, ols, **kw)
     np.testing.assert_array_equal(got.betas, want.betas)
     assert [s.solver_iters for s in got.steps] == [

@@ -115,8 +115,12 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_f32_chunk_program_compiles_without_f64(one_chip):
-    """The served chunk program in f32 holds no f64 op, even with x64 on."""
+def test_f32_chunk_program_compiles_without_f64(one_chip, monkeypatch):
+    """The served chunk program in f32 holds no f64 op, even with x64 on,
+    and pools its prox in the Pallas kernel, as it does on a TPU."""
+    # the engines pick their prox from the backend while they trace; here
+    # the backend is the CPU, so steer them to the TPU's choice
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     f32, i32 = np.float32, np.int32
     B, Nb, Pb, C = SLOTS, N_BUCKET, P_BUCKET, CHUNK
 
@@ -132,3 +136,4 @@ def test_f32_chunk_program_compiles_without_f64(one_chip):
         kkt_tol=1e-4, max_refits=32)
     text = lowered.compile().as_text()
     assert "f64[" not in text
+    assert "tpu_custom_call" in text
