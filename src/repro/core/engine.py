@@ -59,6 +59,7 @@ from .solver import (
     fista_masked,
     fista_shared_masked,
 )
+from .transfer import get, put
 
 __all__ = [
     "EnginePath",
@@ -1130,26 +1131,29 @@ class BatchedPathResult:
         ]
 
 
-def null_gradient(X, y, family: Family) -> np.ndarray:
+def null_gradient(X, y, family: Family, span: dict | None = None
+                  ) -> np.ndarray:
     """∇f(0) reshaped to (p, m) — the quantity both the σ-grid recipe and
-    the first strong-rule step start from."""
+    the first strong-rule step start from.  ``span`` counts its transfers
+    (:mod:`repro.core.transfer`)."""
     p = X.shape[1]
     m = family.n_classes
     beta0 = jnp.zeros((p,) if m == 1 else (p, m), X.dtype)
-    return np.asarray(
-        family.gradient(jnp.asarray(X), jnp.asarray(y), beta0)
-    ).reshape(p, m)
+    return get(family.gradient(put(X, span), put(y, span), beta0),
+               span).reshape(p, m)
 
 
 def null_sigma_grid(X, y, lam, family: Family, *, path_length: int,
                     sigma_ratio: float | None,
-                    grad0: np.ndarray | None = None) -> np.ndarray:
+                    grad0: np.ndarray | None = None,
+                    span: dict | None = None) -> np.ndarray:
     """The paper's σ grid for one problem: σ(1) from the null gradient's
     dual gauge, geometric decay per §3.1.2.  The ONE recipe shared by
     fit_path (both backends), fit_path_batched and cv_path."""
     if grad0 is None:
-        grad0 = null_gradient(X, y, family)
-    s1 = float(path_start_sigma(jnp.asarray(grad0), jnp.asarray(lam)))
+        grad0 = null_gradient(X, y, family, span)
+    s1 = float(get(path_start_sigma(put(grad0, span), put(lam, span)),
+                   span))
     n, p = X.shape
     return sigma_grid(s1, length=path_length, ratio=sigma_ratio, n=n, p=p,
                       dtype=X.dtype)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from .screening import screen_k, screen_masked
+from .transfer import get, put
 
 __all__ = [
     "in_subdifferential",
@@ -103,7 +104,8 @@ def kkt_violations_masked(grad, lam, ever_mask, subset_mask, *, tol: float = 1e-
     return keep & ~ever_mask
 
 
-def kkt_violations(grad, lam, ever_mask, *, subset_mask=None, tol: float = 1e-6):
+def kkt_violations(grad, lam, ever_mask, *, subset_mask=None, tol: float = 1e-6,
+                   span: dict | None = None):
     """Operational violation check used by Algorithms 3 and 4.
 
     Runs Proposition 1 on |grad| restricted to ``subset_mask`` (default: the
@@ -111,7 +113,8 @@ def kkt_violations(grad, lam, ever_mask, *, subset_mask=None, tol: float = 1e-6)
     rule keeps but which are *not* in the working set ``ever_mask``.
 
     Host-side orchestration (the path drivers are NumPy-driven); the scan
-    itself is the jit'd :func:`repro.core.screening.screen_k`.
+    itself is the jit'd :func:`repro.core.screening.screen_k`.  ``span``
+    counts its transfers (:mod:`repro.core.transfer`).
     """
     grad = np.asarray(grad)
     p = grad.size
@@ -131,7 +134,7 @@ def kkt_violations(grad, lam, ever_mask, *, subset_mask=None, tol: float = 1e-6)
     c_pad[: len(idx)] = mag[order] - tol
     lam_pad = np.zeros(p)
     lam_pad[: len(idx)] = np.asarray(lam)[: len(idx)]
-    k = int(screen_k(jnp.asarray(c_pad), jnp.asarray(lam_pad)))
+    k = int(get(screen_k(put(c_pad, span), put(lam_pad, span)), span))
     k = min(k, len(idx))
     kept = idx[order[:k]]
     viol = np.zeros(p, dtype=bool)

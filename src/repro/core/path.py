@@ -44,6 +44,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from ..obs.trace import Trace, keep
 from .engine import EnginePath, null_gradient, null_sigma_grid, path_engine
 from .kkt import kkt_violations
 from .losses import Family
@@ -56,6 +57,7 @@ from .solver import (
     DEFAULT_PATH_TOL,
     fista,
 )
+from .transfer import get, put
 
 __all__ = ["fit_path", "PathResult", "PathStep", "engine_to_path_result"]
 
@@ -86,6 +88,7 @@ class PathResult:
     total_time: float
     total_violations: int
     plan: object | None = None  # repro.api ExecutionPlan (slope_path only)
+    trace: Trace | None = None  # the host driver's phase spans (host only)
 
     @property
     def screen_efficiency(self) -> np.ndarray:
@@ -283,7 +286,14 @@ def _fit_path_host(
     X, y, lam, family, *, screening, path_length, sigma_ratio, sigmas,
     solver_tol, max_iter, kkt_tol, early_stop, verbose,
 ) -> PathResult:
-    t_start = time.perf_counter()
+    # one gap-free trace per path: its spans time the steps and the path,
+    # and count every transfer (the span's dict rides into put/get)
+    tr = Trace()
+
+    def phase(name, step, refit=0, **attrs):
+        return tr.phase(name, step=step, refit=refit, h2d_bytes=0,
+                        fetches=0, d2h_bytes=0, **attrs)
+
     X = np.asarray(X)
     y = np.asarray(y)
     n, p = X.shape
@@ -300,103 +310,114 @@ def _fit_path_host(
     # unit
     prox_method = "pool" if pool_prox_applies(X.dtype) else "stack"
     beta = np.zeros((p, m), dtype=X.dtype)
-    grad_full = null_gradient(X, y, family)
-    null_dev = float(family.loss(jnp.asarray(X), jnp.asarray(y),
-                                 jnp.asarray(_b(beta))))
-
-    if sigmas is None:
-        sigmas = null_sigma_grid(X, y, lam, family, path_length=path_length,
-                                 sigma_ratio=sigma_ratio, grad0=grad_full)
+    with phase("setup", 0) as sp:
+        grad_full = null_gradient(X, y, family, sp)
+        null_dev = float(get(family.loss(put(X, sp), put(y, sp),
+                                         put(_b(beta), sp)), sp))
+        if sigmas is None:
+            sigmas = null_sigma_grid(X, y, lam, family,
+                                     path_length=path_length,
+                                     sigma_ratio=sigma_ratio, grad0=grad_full,
+                                     span=sp)
     sigmas = np.asarray(sigmas)
 
     betas = [beta.copy()]
     steps: list[PathStep] = [
-        PathStep(float(sigmas[0]), np.zeros(p, bool), 0, 0, 0, 0, null_dev, 0, 0.0)
+        PathStep(float(sigmas[0]), np.zeros(p, bool), 0, 0, 0, 0, null_dev, 0,
+                 tr.total_s)
     ]
     prev_active = np.zeros(p, dtype=bool)
     prev_dev = null_dev
     total_viol = 0
 
     for step_idx in range(1, len(sigmas)):
-        t0 = time.perf_counter()
+        t_step = tr.cursor
         sig_prev, sig = float(sigmas[step_idx - 1]), float(sigmas[step_idx])
         lam_next = sig * lam
-        n_screened = p
-        strong_mask = np.ones(p, dtype=bool)
+        with phase("screen", step_idx) as sp:
+            n_screened = p
+            strong_mask = np.ones(p, dtype=bool)
 
-        if screening != "none":
-            k, order = strong_rule(
-                jnp.asarray(grad_full), jnp.asarray(sig_prev * lam), jnp.asarray(lam_next)
-            )
-            kept_flat = np.asarray(order)[: int(k)]
-            strong_mask = np.zeros(p, dtype=bool)
-            strong_mask[np.unique(kept_flat // m)] = True
-            n_screened = int(strong_mask.sum())
+            if screening != "none":
+                k, order = strong_rule(put(grad_full, sp),
+                                       put(sig_prev * lam, sp),
+                                       put(lam_next, sp))
+                kept_flat = get(order, sp)[: int(get(k, sp))]
+                strong_mask = np.zeros(p, dtype=bool)
+                strong_mask[np.unique(kept_flat // m)] = True
+                n_screened = int(strong_mask.sum())
 
-        if screening == "strong":
-            E = strong_mask | prev_active
-        elif screening == "previous":
-            E = prev_active.copy()
-            if not E.any():
-                E = strong_mask.copy()
-        else:
-            E = np.ones(p, dtype=bool)
-        if E.sum() >= 0.5 * p:
-            # screening keeps most predictors (n ≳ p regime): solve the full
-            # problem — shares one compiled shape with the unscreened path
-            E = np.ones(p, dtype=bool)
+            if screening == "strong":
+                E = strong_mask | prev_active
+            elif screening == "previous":
+                E = prev_active.copy()
+                if not E.any():
+                    E = strong_mask.copy()
+            else:
+                E = np.ones(p, dtype=bool)
+            if E.sum() >= 0.5 * p:
+                # screening keeps most predictors (n ≳ p regime): solve the
+                # full problem — shares one compiled shape with the
+                # unscreened path
+                E = np.ones(p, dtype=bool)
 
         viol_count = 0
         refits = 0
         iters_total = 0
         checked_full = False
         while True:
-            E_idx = np.nonzero(E)[0]
-            width = max(len(E_idx), 1)
-            bucket = _bucket(width, p)
-            Xs = np.zeros((n, bucket), dtype=X.dtype)
-            Xs[:, :width] = X[:, E_idx] if len(E_idx) else 0.0
-            lam_sub = np.zeros(bucket * m, dtype=lam.dtype)
-            lam_sub[: len(E_idx) * m] = lam_next[: len(E_idx) * m]
-            warm = np.zeros((bucket, m), dtype=X.dtype)
-            if len(E_idx):
-                warm[:width] = beta[E_idx]
+            with phase("gather", step_idx, refits):
+                E_idx = np.nonzero(E)[0]
+                width = max(len(E_idx), 1)
+                bucket = _bucket(width, p)
+                Xs = np.zeros((n, bucket), dtype=X.dtype)
+                Xs[:, :width] = X[:, E_idx] if len(E_idx) else 0.0
+                lam_sub = np.zeros(bucket * m, dtype=lam.dtype)
+                lam_sub[: len(E_idx) * m] = lam_next[: len(E_idx) * m]
+                warm = np.zeros((bucket, m), dtype=X.dtype)
+                if len(E_idx):
+                    warm[:width] = beta[E_idx]
 
-            res = fista(
-                jnp.asarray(Xs),
-                jnp.asarray(y),
-                jnp.asarray(lam_sub),
-                jnp.asarray(warm if m > 1 else warm[:, 0]),
-                family,
-                max_iter=max_iter,
-                tol=solver_tol,
-                prox_method=prox_method,
-            )
-            iters_total += int(res.iters)
-            beta_sub = np.asarray(res.beta).reshape(bucket, m)
-            beta = np.zeros((p, m), dtype=X.dtype)
-            if len(E_idx):
-                beta[E_idx] = beta_sub[:width]
+            with phase("solve", step_idx, refits, width=width,
+                       bucket=bucket) as sp:
+                res = fista(
+                    put(Xs, sp),
+                    put(y, sp),
+                    put(lam_sub, sp),
+                    put(warm if m > 1 else warm[:, 0], sp),
+                    family,
+                    max_iter=max_iter,
+                    tol=solver_tol,
+                    prox_method=prox_method,
+                )
+                iters_total += int(get(res.iters, sp))
+                beta_sub = get(res.beta, sp).reshape(bucket, m)
+                beta = np.zeros((p, m), dtype=X.dtype)
+                if len(E_idx):
+                    beta[E_idx] = beta_sub[:width]
 
-            grad_full = np.asarray(
-                family.gradient(jnp.asarray(X), jnp.asarray(y),
-                                jnp.asarray(_b(beta)))
-            ).reshape(p, m)
+            with phase("gradient", step_idx, refits) as sp:
+                grad_full = get(family.gradient(put(X, sp), put(y, sp),
+                                                put(_b(beta), sp)),
+                                sp).reshape(p, m)
 
             if screening == "none":
                 break
 
-            ever_flat = np.repeat(E, m)
-            if screening == "previous" and not checked_full:
-                subset_flat = np.repeat(strong_mask, m)
-                viol = kkt_violations(
-                    grad_full.ravel(), lam_next, ever_flat, subset_mask=subset_flat, tol=kkt_tol
-                )
-                if not viol.any():
-                    checked_full = True
-                    viol = kkt_violations(grad_full.ravel(), lam_next, ever_flat, tol=kkt_tol)
-            else:
-                viol = kkt_violations(grad_full.ravel(), lam_next, ever_flat, tol=kkt_tol)
+            with phase("kkt", step_idx, refits) as sp:
+                ever_flat = np.repeat(E, m)
+                if screening == "previous" and not checked_full:
+                    subset_flat = np.repeat(strong_mask, m)
+                    viol = kkt_violations(
+                        grad_full.ravel(), lam_next, ever_flat,
+                        subset_mask=subset_flat, tol=kkt_tol, span=sp)
+                    if not viol.any():
+                        checked_full = True
+                        viol = kkt_violations(grad_full.ravel(), lam_next,
+                                              ever_flat, tol=kkt_tol, span=sp)
+                else:
+                    viol = kkt_violations(grad_full.ravel(), lam_next,
+                                          ever_flat, tol=kkt_tol, span=sp)
 
             if not viol.any():
                 break
@@ -409,9 +430,10 @@ def _fit_path_host(
             E[viol_rows] = True
             refits += 1
 
-        active = np.abs(beta).max(axis=1) > 0
-        dev = float(family.loss(jnp.asarray(X), jnp.asarray(y),
-                                jnp.asarray(_b(beta))))
+        with phase("loss", step_idx, refits) as sp:
+            active = np.abs(beta).max(axis=1) > 0
+            dev = float(get(family.loss(put(X, sp), put(y, sp),
+                                        put(_b(beta), sp)), sp))
         total_viol += viol_count
         betas.append(beta.copy())
         steps.append(
@@ -424,7 +446,7 @@ def _fit_path_host(
                 refits=refits,
                 deviance=dev,
                 solver_iters=iters_total,
-                wall_time=time.perf_counter() - t0,
+                wall_time=tr.cursor - t_step,
             )
         )
         prev_active = active
@@ -439,6 +461,7 @@ def _fit_path_host(
             break
         prev_dev = dev
 
+    keep(tr)
     arr = np.stack(betas)
     if m == 1:
         arr = arr[:, :, 0]
@@ -447,6 +470,7 @@ def _fit_path_host(
         sigmas=sigmas[: len(betas)],
         steps=steps,
         lam=lam,
-        total_time=time.perf_counter() - t_start,
+        total_time=tr.total_s,
         total_violations=total_viol,
+        trace=tr,
     )

@@ -113,4 +113,6 @@ def prox_pool_kernel_call(w: jax.Array, *, interpret: bool = False) -> jax.Array
             pltpu.SMEM((p,), jnp.float32),
         ],
         interpret=interpret,
+        # the benchmark's trace reduction finds the kernel by this name
+        name="prox_pool",
     )(w)

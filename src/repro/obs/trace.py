@@ -29,18 +29,62 @@ section and ``examples/serve_paths.py`` for a rendered timeline):
             span named by their ``parent`` attr / cursor position)
 ========== ==========================================================
 
+The host path driver (``core/path.py``) keeps one trace per path, marked
+phase by phase with :meth:`Trace.phase`, which also writes each phase as
+a ``repro.path.<name>`` profiler annotation on the device trace's clock:
+
+============ ========================================================
+``setup``    null gradient, null deviance, σ grid (step 0)
+``screen``   strong rule and screened mask, once per σ-step
+``gather``   host column gather, λ slice and warm start (per refit)
+``solve``    FISTA sub-solve through the fetch of β (attrs ``width``,
+             ``bucket``)
+``gradient`` full gradient, with its upload of X and its fetch
+``kkt``      KKT check of the full gradient
+``loss``     the step's deviance (the step's last span)
+============ ========================================================
+
+Every span of a path trace carries ``step``, ``refit``, ``h2d_bytes``
+(host arrays turned into device arrays), ``fetches`` (blocking
+device→host reads) and ``d2h_bytes``.  Each finished path trace is kept
+in a bounded flight recorder, :func:`keep` / :func:`recent`: the last
+:data:`RECENT_PATHS` paths of the process.
+
 Threading contract: one request's trace is only ever mutated by the thread
 currently driving that request (submit thread through admission, dispatcher
 thread afterwards — handoff sequenced by the service lock), so spans need
-no lock of their own.  stdlib-only module.
+no lock of their own.  stdlib-only at import (:mod:`.profile` reaches
+jax lazily).
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+import threading
 import time
 
-__all__ = ["Span", "Trace"]
+from .profile import annotate
+
+__all__ = ["Span", "Trace", "keep", "recent", "RECENT_PATHS"]
+
+RECENT_PATHS = 64
+_recent: collections.deque = collections.deque(maxlen=RECENT_PATHS)
+_recent_lock = threading.Lock()
+
+
+def keep(trace: "Trace") -> None:
+    """Add a finished trace to the flight recorder (the oldest of more
+    than :data:`RECENT_PATHS` is dropped)."""
+    with _recent_lock:
+        _recent.append(trace)
+
+
+def recent() -> list:
+    """The recorder's traces, oldest first."""
+    with _recent_lock:
+        return list(_recent)
 
 
 @dataclasses.dataclass
@@ -85,6 +129,16 @@ class Trace:
         self.spans.append(span)
         self.cursor = t_end
         return span
+
+    @contextlib.contextmanager
+    def phase(self, name: str, **attrs):
+        """``with tr.phase("solve", step=3) as span:`` — run the body under
+        the profiler annotation ``repro.path.<name>`` and :meth:`mark` it on
+        exit.  Yields the span's attribute dict, so the body can add its
+        counts before the span closes."""
+        with annotate(f"repro.path.{name}"):
+            yield attrs
+        self.mark(name, **attrs)
 
     def child(self, name: str, t0: float | None = None,
               t1: float | None = None, *, parent: str | None = None,

@@ -8,6 +8,7 @@ try:
 except ImportError:  # minimal image: fall back to seeded random fuzzing
     from _hypothesis_fallback import given, settings, strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import prox_sorted_l1
@@ -27,6 +28,7 @@ from repro.kernels import (
     slope_residual_masked,
 )
 from repro.kernels import ref as R
+from repro.kernels.prox_sorted_l1 import prox_pool_kernel_call
 
 SHAPES = [(7, 13, 1), (64, 128, 3), (33, 257, 4), (256, 512, 1), (129, 1025, 2)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -320,3 +322,14 @@ def test_prox_pool_monotone_output(rng):
         out = np.asarray(prox_pool(w))
         assert np.all(np.diff(out) <= 1e-5)
         assert np.all(out >= 0)
+
+
+def test_prox_pool_kernel_is_named():
+    # the device trace names the kernel after the pallas_call's name, and
+    # the benchmark's prox share finds it by that name
+    jaxpr = jax.make_jaxpr(
+        lambda w: prox_pool_kernel_call(w, interpret=True))(
+        jnp.ones(16, jnp.float32))
+    (eqn,) = [e for e in jaxpr.jaxpr.eqns
+              if e.primitive.name == "pallas_call"]
+    assert eqn.params["name"] == "prox_pool"

@@ -18,16 +18,22 @@ Contracts under test:
   tracing stays OFF by default (`resp.trace is None`);
 * `SolverPolicy(telemetry=...)` attaches a `PathTrace` whose screened-set
   counts match the fit's own arrays;
-* exporters round-trip through JSONL and render Prometheus text.
+* exporters round-trip through JSONL and render Prometheus text;
+* the host path driver keeps one gap-free phase trace per path
+  (`PathResult.trace`), counts its transfers where they happen, keeps the
+  trace in the bounded `recent()` recorder, and writes each phase as a
+  `repro.path.<name>` profiler annotation without moving a coefficient.
 """
 
+import glob
 import json
+import re
 import threading
 
 import numpy as np
 import pytest
 
-from repro.api import PathSpec, Problem, SolverPolicy, slope_path
+from repro.api import LambdaSpec, PathSpec, Problem, SolverPolicy, slope_path
 from repro.core import bh_sequence, ols
 from repro.obs import (
     MetricsRegistry,
@@ -38,6 +44,7 @@ from repro.obs import (
     trace_events,
     write_jsonl,
 )
+from repro.obs import trace as obs_trace
 from repro.serve import AsyncPathService, PathService, ProgramCache
 
 KW = dict(path_length=6, solver_tol=1e-10, max_iter=20000)
@@ -329,3 +336,116 @@ def test_prometheus_text_exposition():
     assert text.count("# TYPE serve_flush counter") == 1
     assert 'quantile="0.95"' in text
     assert text.endswith("\n")
+
+
+# ---------------------------------------------------------------------------
+# host path driver: phase spans, transfer counters, flight recorder
+# ---------------------------------------------------------------------------
+
+HOST_SPEC = PathSpec(lam=LambdaSpec("bh", q=0.1), path_length=10,
+                     early_stop=False)
+HOST_POLICY = SolverPolicy(backend="host")
+
+
+def _host_problem():
+    X, y = _problem(n=40, p=300, seed=11, k=5)
+    return Problem(X, y, family=ols)
+
+
+@pytest.fixture(scope="module")
+def host_fit():
+    prob = _host_problem()
+    return prob, slope_path(prob, HOST_SPEC, HOST_POLICY)
+
+
+def test_trace_phase_marks_on_exit_with_the_body_s_counts():
+    tr = Trace()
+    with tr.phase("solve", step=1, fetches=0) as span:
+        span["fetches"] += 2
+        assert tr.spans == []  # marked on exit, not before
+    (s,) = tr.top()
+    assert s.name == "solve" and s.attrs == {"step": 1, "fetches": 2}
+    assert tr.contiguous() and s.t1 == tr.cursor
+
+
+def test_host_path_trace_is_gap_free_and_times_the_path(host_fit):
+    _, res = host_fit
+    tr = res.trace
+    assert isinstance(tr, Trace)
+    assert tr.contiguous()
+    assert res.total_time == tr.total_s
+    # a step's wall time is its spans (step 0's is the set-up)
+    for i, st in enumerate(res.steps):
+        spans = [s for s in tr.top() if s.attrs["step"] == i]
+        assert st.wall_time == pytest.approx(
+            sum(s.duration_s for s in spans), rel=1e-9, abs=1e-12)
+    assert sum(st.wall_time for st in res.steps) == pytest.approx(
+        res.total_time, rel=1e-9)
+
+
+def test_host_path_spans_follow_the_driver_s_phases(host_fit):
+    _, res = host_fit
+    tops = res.trace.top()
+    names = " ".join(s.name for s in tops)
+    assert re.fullmatch(
+        r"setup( screen( gather solve gradient kkt)+ loss){9}", names), names
+    solves = [s for s in tops if s.name == "solve"]
+    assert len(solves) == sum(st.refits + 1 for st in res.steps[1:])
+    for s in tops:
+        assert {"step", "refit", "h2d_bytes", "fetches",
+                "d2h_bytes"} <= set(s.attrs)
+    for s in solves:
+        assert s.attrs["width"] <= s.attrs["bucket"] <= 300
+        # FISTA's uploads (Xs, y, λ, warm start), then iters and β back
+        assert s.attrs["fetches"] == 2
+    for st, loss in zip(res.steps[1:],
+                        [s for s in tops if s.name == "loss"]):
+        assert loss.attrs["refit"] == st.refits
+
+
+def test_host_path_counts_each_gradient_s_transfers(host_fit):
+    prob, res = host_fit
+    X, y = np.asarray(prob.X), np.asarray(prob.y)
+    beta_bytes = X.shape[1] * X.dtype.itemsize
+    grads = [s for s in res.trace.top() if s.name == "gradient"]
+    assert grads
+    for s in grads:
+        assert s.attrs["h2d_bytes"] == X.nbytes + y.nbytes + beta_bytes
+        assert s.attrs["fetches"] == 1
+        assert s.attrs["d2h_bytes"] == beta_bytes
+    for s in res.trace.top():
+        if s.name == "gather":  # host work only
+            assert s.attrs["h2d_bytes"] == s.attrs["fetches"] == 0
+        if s.name in ("kkt", "screen", "loss", "setup"):
+            assert s.attrs["fetches"] >= 1 and s.attrs["h2d_bytes"] > 0
+
+
+def test_recent_keeps_the_last_path_traces_within_its_bound(host_fit):
+    _, res = host_fit
+    assert any(t is res.trace for t in obs_trace.recent())
+    for _ in range(obs_trace.RECENT_PATHS + 5):
+        obs_trace.keep(Trace())
+    kept = obs_trace.recent()
+    assert len(kept) == obs_trace.RECENT_PATHS
+    assert not any(t is res.trace for t in kept)
+    # device backends keep no host trace
+    other = slope_path(_host_problem(), HOST_SPEC,
+                       SolverPolicy(backend="masked"))
+    assert other.trace is None
+
+
+def test_host_path_is_bitwise_under_a_profiler_and_annotated(host_fit,
+                                                             tmp_path):
+    import jax
+
+    prob, res = host_fit
+    with jax.profiler.trace(str(tmp_path)):
+        traced = slope_path(prob, HOST_SPEC, HOST_POLICY)
+    np.testing.assert_array_equal(traced.betas, res.betas)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes for line in plane.lines
+             for e in line.events}
+    for phase in ("setup", "screen", "gather", "solve", "gradient", "kkt",
+                  "loss"):
+        assert f"repro.path.{phase}" in names
