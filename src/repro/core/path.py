@@ -390,7 +390,11 @@ def _fit_path_host(
                     tol=solver_tol,
                     prox_method=prox_method,
                 )
-                iters_total += int(get(res.iters, sp))
+                iters, live, full = get(
+                    (res.iters, res.prox_live, res.prox_full), sp)
+                iters_total += int(iters)
+                # how much of the pool prox's loops ran (kernels/prox_sorted_l1)
+                sp["prox_live"], sp["prox_full"] = int(live), int(full)
                 beta_sub = get(res.beta, sp).reshape(bucket, m)
                 beta = np.zeros((p, m), dtype=X.dtype)
                 if len(E_idx):

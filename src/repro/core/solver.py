@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .losses import Family, matmul
-from .sorted_l1 import prox_sorted_l1_with_norm, sorted_l1_norm
+from .sorted_l1 import _prox_sorted_l1_with_norm_live, sorted_l1_norm
 
 __all__ = ["fista", "fista_masked", "fista_shared_masked", "fista_compact",
            "default_L0", "FistaResult",
@@ -75,6 +75,11 @@ class FistaResult(NamedTuple):
     objective: jax.Array
     converged: jax.Array
     L: jax.Array  # final curvature estimate (warm-start for the next solve)
+    # Σ over every prox call, line-search tries included, of the prox
+    # input's live bound q (``kernels.prox_sorted_l1.live_bound``) and of
+    # its width: the share of the pool kernel's loops that ran
+    prox_live: jax.Array
+    prox_full: jax.Array
 
 
 class _State(NamedTuple):
@@ -85,6 +90,8 @@ class _State(NamedTuple):
     obj: jax.Array
     it: jax.Array
     done: jax.Array
+    prox_live: jax.Array
+    prox_full: jax.Array
 
 
 @functools.partial(
@@ -175,14 +182,14 @@ def fista(
         gz = mask_grad(matmul(X.T, rz))
 
         def bt_cond(carry):
-            L, x_new, fx, J, ok, tries = carry
+            L, x_new, fx, J, ok, tries, live = carry
             return (~ok) & (tries < max_backtrack)
 
         def bt_body(carry):
-            L, _, _, _, _, tries = carry
+            L, _, _, _, _, tries, live = carry
             # prox at λ/L; its by-product norm is ⟨x_sorted, λ/L⟩, so scale
             # by L to recover J(x_new; λ) — no extra sort for the objective
-            x_new, J_scaled = prox_sorted_l1_with_norm(
+            x_new, J_scaled, bound = _prox_sorted_l1_with_norm_live(
                 jnp.ravel(z - gz / L), lam / L, method=prox_method
             )
             x_new = x_new.reshape(z.shape)
@@ -205,11 +212,12 @@ def fista(
             ok = jnp.where(slack * jnp.abs(q) <= 0.25 * L * dd,
                            by_value, by_predictor)
             L_next = jnp.where(ok, L, L * 2.0)
-            return L_next, x_new, fx, J_scaled * L, ok, tries + 1
+            return L_next, x_new, fx, J_scaled * L, ok, tries + 1, live + bound
 
-        L, x_new, fx, J_new, _, _ = lax.while_loop(
+        L, x_new, fx, J_new, _, tries, live = lax.while_loop(
             bt_cond, bt_body,
-            (state.L, z, fz, jnp.zeros_like(fz), jnp.bool_(False), jnp.int32(0)),
+            (state.L, z, fz, jnp.zeros_like(fz), jnp.bool_(False), jnp.int32(0),
+             jnp.int32(0)),
         )
 
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * state.t**2))
@@ -239,7 +247,8 @@ def fista(
                                    * jnp.maximum(1.0, jnp.max(jnp.abs(x_new))))
         done = plateau & stationary
         # mild decrease of L lets the step size recover after conservative phases
-        return _State(x_new, z_new, t_new, L * 0.95, obj_new, state.it + 1, done)
+        return _State(x_new, z_new, t_new, L * 0.95, obj_new, state.it + 1, done,
+                      state.prox_live + live, state.prox_full + tries * z.size)
 
     def cond(state: _State):
         return (~state.done) & (state.it < max_iter)
@@ -252,9 +261,12 @@ def fista(
         obj=obj_fn(beta0.astype(dtype)),
         it=jnp.int32(0),
         done=jnp.bool_(False),
+        prox_live=jnp.int32(0),
+        prox_full=jnp.int32(0),
     )
     final = lax.while_loop(cond, step, init)
-    return FistaResult(final.x, final.it, final.obj, final.done, final.L)
+    return FistaResult(final.x, final.it, final.obj, final.done, final.L,
+                       final.prox_live, final.prox_full)
 
 
 def fista_masked(
@@ -366,4 +378,4 @@ def fista_compact(
     res = fista(Xc, y, lam_c, b0, family, **kw)
     bc = res.beta * (valid if res.beta.ndim == 1 else valid[:, None])
     beta = jnp.zeros(beta0.shape, dtype).at[idx].set(bc)
-    return FistaResult(beta, res.iters, res.objective, res.converged, res.L)
+    return res._replace(beta=beta)

@@ -176,8 +176,10 @@ def isotonic_decreasing_minimax(y: jax.Array) -> jax.Array:
 def isotonic_decreasing_pool(y: jax.Array) -> jax.Array:
     """:func:`isotonic_decreasing` clipped at 0, by the Pallas kernel
     (:func:`repro.kernels.ops.prox_pool`): the same stack PAVA, run on the
-    TPU core's scalar unit out of its scalar memory.  f32 only.  Under
-    ``vmap`` the rows go through the kernel one after another."""
+    TPU core's scalar unit out of its scalar memory, over the first q
+    elements only (q = 1 + the last index where ``y`` > 0; past it the
+    result is 0).  f32 only.  Under ``vmap`` the rows go through the
+    kernel one after another, each with its own q."""
     from ..kernels.ops import prox_pool  # the kernels import this module
 
     return prox_pool(y)
@@ -213,6 +215,16 @@ def prox_sorted_l1_with_norm(v: jax.Array, lam: jax.Array, *,
     sorted magnitude vector of the result — so J(x; λ) = ⟨x_sorted, λ⟩ falls
     out for free, saving the solver a per-iteration sort.
     """
+    return _prox_sorted_l1_with_norm_live(v, lam, method=method)[:2]
+
+
+@functools.partial(jax.jit, static_argnames=("method",))
+def _prox_sorted_l1_with_norm_live(v, lam, *, method):
+    """:func:`prox_sorted_l1_with_norm` and the live bound q of its sorted
+    input (:func:`repro.kernels.prox_sorted_l1.live_bound`), whatever the
+    ``method``: the pool kernel loops over the first q elements only."""
+    from ..kernels.prox_sorted_l1 import live_bound
+
     shape = v.shape
     v = jnp.ravel(v)
     lam = jnp.ravel(lam).astype(v.dtype)
@@ -229,7 +241,8 @@ def prox_sorted_l1_with_norm(v: jax.Array, lam: jax.Array, *,
     x_sorted = jnp.maximum(iso, 0)
     x = jnp.zeros_like(v).at[order].set(x_sorted)
     return ((sign * x).reshape(shape),
-            jnp.dot(x_sorted, lam, precision=lax.Precision.HIGHEST))
+            jnp.dot(x_sorted, lam, precision=lax.Precision.HIGHEST),
+            live_bound(w))
 
 
 @functools.partial(jax.jit, static_argnames=("method",))

@@ -29,11 +29,17 @@ def put(a, span: dict | None = None) -> jax.Array:
     return out
 
 
-def get(a, span: dict | None = None) -> np.ndarray:
-    """``np.asarray(a)`` of a device value: one blocking device→host read,
-    counted in ``span["fetches"]`` with its bytes in ``span["d2h_bytes"]``."""
-    out = np.asarray(a)
+def get(a, span: dict | None = None):
+    """``np.asarray(a)`` of a device value, or a tuple of them for a tuple:
+    one blocking device→host read, counted in ``span["fetches"]`` with its
+    bytes in ``span["d2h_bytes"]``."""
+    if isinstance(a, tuple):
+        out = tuple(np.asarray(x) for x in jax.device_get(a))
+        nbytes = sum(x.nbytes for x in out)
+    else:
+        out = np.asarray(a)
+        nbytes = out.nbytes
     if span is not None:
         span["fetches"] += 1
-        span["d2h_bytes"] += out.nbytes
+        span["d2h_bytes"] += nbytes
     return out

@@ -38,7 +38,8 @@ a ``repro.path.<name>`` profiler annotation on the device trace's clock:
 ``screen``   strong rule and screened mask, once per σ-step
 ``gather``   host column gather, λ slice and warm start (per refit)
 ``solve``    FISTA sub-solve through the fetch of β (attrs ``width``,
-             ``bucket``)
+             ``bucket``, and ``prox_live`` / ``prox_full``: FISTA's Σ of
+             the prox's live bound and of its width)
 ``gradient`` full gradient, with its upload of X and its fetch
 ``kkt``      KKT check of the full gradient
 ``loss``     the step's deviance (the step's last span)

@@ -28,7 +28,8 @@ from repro.kernels import (
     slope_residual_masked,
 )
 from repro.kernels import ref as R
-from repro.kernels.prox_sorted_l1 import prox_pool_kernel_call
+from repro.core.sorted_l1 import isotonic_decreasing, isotonic_decreasing_pool
+from repro.kernels.prox_sorted_l1 import live_bound, prox_pool_kernel_call
 
 SHAPES = [(7, 13, 1), (64, 128, 3), (33, 257, 4), (256, 512, 1), (129, 1025, 2)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -333,3 +334,72 @@ def test_prox_pool_kernel_is_named():
     (eqn,) = [e for e in jaxpr.jaxpr.eqns
               if e.primitive.name == "pallas_call"]
     assert eqn.params["name"] == "prox_pool"
+
+
+def _pool_input(kind, p=96, seed=7, k=32):
+    """``w = |v|↓ − λ`` as the prox builds it (``k`` leading |v| before a
+    tail), and the live bound q that the kernel must stop at (1 + the last
+    index where w is not ≤ 0)."""
+    rng = np.random.default_rng(seed)
+    lam = np.sort(np.abs(rng.normal(size=p)))[::-1] + 0.5
+    mag = np.sort(np.abs(rng.normal(size=p)) * 2)[::-1]
+    if kind == "all_nonpositive":
+        mag = mag * 0.1
+    elif kind == "live_to_the_end":
+        mag = mag + 10.0
+    elif kind == "zero_tail":  # the host driver's padded bucket
+        mag[k:] = 0.0
+        lam[k:] = 0.0
+    elif kind == "minus_lam_tail":  # masked columns of the engines
+        mag[k:] = 0.0
+    elif kind == "one_cluster":  # near σ(1): one pooled block
+        mag[:] = lam.mean() + 0.01
+    elif kind == "nan_past_last_positive":
+        mag[k:] = 0.0
+    w = (mag - lam).astype(np.float32)
+    if kind == "nan_past_last_positive":
+        w[k + 5] = np.nan
+    live = np.flatnonzero(~(w <= 0))
+    return w, int(live[-1]) + 1 if live.size else 0
+
+
+@pytest.mark.parametrize("kind", [
+    "all_nonpositive", "live_to_the_end", "zero_tail", "minus_lam_tail",
+    "one_cluster", "nan_past_last_positive"])
+def test_prox_pool_kernel_stops_at_the_live_bound(kind):
+    """The bounded loops return the whole-width PAVA's values exactly:
+    every output past q is 0, and a non-finite input still shows."""
+    w, q = _pool_input(kind)
+    assert int(live_bound(jnp.asarray(w))) == q
+    got = np.asarray(jax.jit(
+        lambda x: prox_pool_kernel_call(x, interpret=True))(jnp.asarray(w)))
+    want_ref = np.asarray(R.prox_pool_ref(jnp.asarray(w)))
+    want_stack = np.maximum(np.asarray(isotonic_decreasing(jnp.asarray(w))),
+                            0)
+    assert np.array_equal(got, want_ref, equal_nan=True)
+    assert np.array_equal(got, want_stack, equal_nan=True)
+    assert not got[q:].any()
+    if kind == "all_nonpositive":
+        assert q == 0
+    if kind == "live_to_the_end":
+        assert q == len(w)
+    if kind == "one_cluster":
+        assert q == len(w) and np.unique(got).size == 1 and got[0] > 0
+    if kind == "nan_past_last_positive":
+        assert np.isnan(got[q - 1])
+
+
+def test_prox_pool_rows_keep_their_own_live_bound():
+    """Under ``vmap`` (the engines' batched prox) every row stops at its
+    own q."""
+    kinds = (("all_nonpositive", 32), ("zero_tail", 32),
+             ("live_to_the_end", 32), ("minus_lam_tail", 8))
+    rows = [_pool_input(kind, seed=i, k=k)
+            for i, (kind, k) in enumerate(kinds)]
+    W = jnp.asarray(np.stack([w for w, _ in rows]))
+    assert len({q for _, q in rows}) == len(rows)
+    np.testing.assert_array_equal(np.asarray(jax.vmap(live_bound)(W)),
+                                  [q for _, q in rows])
+    got = np.asarray(jax.jit(jax.vmap(isotonic_decreasing_pool))(W))
+    for row, (w, _) in zip(got, rows):
+        assert np.array_equal(row, np.asarray(R.prox_pool_ref(jnp.asarray(w))))

@@ -449,3 +449,46 @@ def test_host_path_is_bitwise_under_a_profiler_and_annotated(host_fit,
     for phase in ("setup", "screen", "gather", "solve", "gradient", "kkt",
                   "loss"):
         assert f"repro.path.{phase}" in names
+
+
+@pytest.fixture
+def prox_inputs(monkeypatch):
+    """Record the sorted input ``w`` of every prox call FISTA makes (on
+    the CPU the ``stack`` PAVA), by a callback beside the PAVA; the jit
+    caches are cleared so the solves trace anew, with and then without
+    it."""
+    import jax
+
+    from repro.core import sorted_l1
+
+    calls = []
+    stack = sorted_l1.isotonic_decreasing
+
+    def recorded(w):
+        jax.debug.callback(lambda a: calls.append(np.asarray(a)), w)
+        return stack(w)
+
+    monkeypatch.setattr(sorted_l1, "isotonic_decreasing", recorded)
+    jax.clear_caches()
+    yield calls
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def test_host_path_counts_the_prox_s_live_bound(prox_inputs):
+    """Σ ``prox_live`` and Σ ``prox_full`` over the ``solve`` spans equal
+    a NumPy recount of each prox call's live bound q (1 + the last index
+    where w is not ≤ 0) and width, line-search tries included; fetching
+    them with the iteration count adds no fetch."""
+    res = slope_path(_host_problem(), HOST_SPEC, HOST_POLICY)
+    solves = [s for s in res.trace.top() if s.name == "solve"]
+    assert prox_inputs and len(prox_inputs) >= sum(
+        st.solver_iters for st in res.steps)
+    live = sum(int(np.flatnonzero(~(w <= 0))[-1]) + 1 if (~(w <= 0)).any()
+               else 0 for w in prox_inputs)
+    full = sum(w.size for w in prox_inputs)
+    assert sum(s.attrs["prox_live"] for s in solves) == live
+    assert sum(s.attrs["prox_full"] for s in solves) == full
+    assert 0 < live < full
+    for s in solves:
+        assert s.attrs["fetches"] == 2
